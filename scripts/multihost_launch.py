@@ -7,6 +7,13 @@ process sees K virtual CPU devices. The processes rendezvous through the
 fleet dir's heartbeat leases and exchange merge/metrics partials through
 its file exchange — a real multi-process elastic fleet, no injector.
 
+This is the CPU-fleet launcher, not a chip path: every child is pinned to
+the CPU (``JAX_PLATFORMS=cpu`` unless the caller sets it). A TPU chip
+belongs to one process at a time, so N children on one host cannot share
+it; a chip run is one process (``chip_smoke.py``), and a multi-host TPU
+fleet starts one ``repro.launch.train`` per host with the ``REPRO_MH_*``
+environment (README, "Multi-host fleets").
+
 Exit status is 0 iff every process that was not deliberately killed
 exited 0. Per-process output is teed to ``<fleet-dir>/logs/proc<i>.log``
 and tails are printed on completion.

@@ -140,10 +140,9 @@ def normalized_merge(
         merged = merge_pytree(replicas, alphas)
     else:
         merged = tu.tree_weighted_sum_replicas(replicas, alphas)
-    if axis_name is not None:
-        # per-shard partials -> the collective merge (momentum term must see
-        # the complete weighted sum, so the psum sits between the two)
-        merged = tu.tree_map(lambda l: jax.lax.psum(l, axis_name), merged)
+    # per-shard partials -> the collective merge (momentum term must see
+    # the complete weighted sum, so the psum sits between the two)
+    merged = tu.tree_map(lambda l: tu.replica_all_sum(l, axis_name), merged)
     if not momentum:
         return merged
     return tu.tree_map(

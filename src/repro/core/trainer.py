@@ -64,7 +64,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ElasticConfig
@@ -357,7 +356,8 @@ class ElasticTrainer:
                 # unconditionally above, so every shard participates)
                 live_local = update_mask.max()
                 live = (
-                    jax.lax.pmax(live_local, axis) if axis else live_local
+                    jax.lax.pmax(live_local, axis) if tu.spans_shards(axis)
+                    else live_local
                 ) > 0
                 new_replicas = tu.tree_map(
                     lambda a, r: jnp.where(live, a, r), adjusted, new_replicas
@@ -404,8 +404,7 @@ class ElasticTrainer:
                             jnp.sum(mask),
                         ]
                     )
-                    if axis:
-                        sums = jax.lax.psum(sums, axis)
+                    sums = tu.replica_all_sum(sums, axis)
                     if raw_stats:
                         return (new_reps, new_mom), sums
                     denom = jnp.maximum(sums[3], 1.0)
@@ -468,12 +467,12 @@ class ElasticTrainer:
             )
             self._merge = jax.jit(merge_fn, static_argnames=("gamma",))
             self._norms = jax.jit(lambda r: tu.tree_l2_norm_per_replica(r))
+            self._eval = jax.jit(loss_fn)
         else:
             # the traced bodies are mesh-independent; shard_map binds them
             # to self.mesh per shard count, cached across resizes
-            self._bodies = (round_body, megabatch_fn, merge_fn, donate)
+            self._bodies = (round_body, megabatch_fn, merge_fn, loss_fn, donate)
             self._install_sharded_executors()
-        self._eval = jax.jit(loss_fn)
 
         def finite_rows(tree):
             """(R,) bool: replica i's leaves are all finite. Read-only — the
@@ -519,7 +518,7 @@ class ElasticTrainer:
         if execs is None:
             execs = self._build_sharded_executors(*self._bodies)
             self._exec_cache[key] = execs
-        self._round, self._megabatch, self._merge, self._norms = execs
+        self._round, self._megabatch, self._merge, self._norms, self._eval = execs
         if self._span is not None:
             partial = self._span_exec_cache.get(key)
             if partial is None:
@@ -527,21 +526,21 @@ class ElasticTrainer:
                 # local share of the Alg.-2 weighted sum: psum over the
                 # *local* mesh only; the exchange completes it (host span)
                 partial = jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         lambda r, a: asgd.normalized_merge(
                             r, a, None, None, 0.0, axis_name=REPLICA_AXIS
                         ),
                         mesh=mesh,
                         in_specs=(s0, s0),
                         out_specs=P(),
-                        check_rep=False,
+                        check_vma=False,
                     )
                 )
                 self._span_exec_cache[key] = partial
             self._span_partial = partial
 
     def _build_sharded_executors(self, round_body, megabatch_fn, merge_fn,
-                                 donate):
+                                 loss_fn, donate):
         """shard_map the engine entry points over the 1-D replica mesh.
 
         The traced bodies are the *same* functions the vmap placement jits —
@@ -551,7 +550,7 @@ class ElasticTrainer:
         static argument, so the stable per-trainer object is closed over
         instead (same jit-cache behavior; the wrappers assert call sites
         keep passing the identical object). Returns the executor tuple
-        ``(round, megabatch, merge, norms)``; the wrappers carry their
+        ``(round, megabatch, merge, norms, eval)``; the wrappers carry their
         underlying jitted callable as ``_jit`` for cache introspection.
         """
         transforms = self._transforms
@@ -560,7 +559,7 @@ class ElasticTrainer:
         timer = self._shard_timer
 
         jit_round = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda r, m, b, lr, mask: round_body(
                     r, m, b, lr, mask, transforms
                 ),
@@ -569,7 +568,7 @@ class ElasticTrainer:
                 in_specs=(s0, s0, s0, s0, s0),
                 # per-replica metric vectors gather back to (R,)
                 out_specs=(s0, s0, s0),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -592,14 +591,14 @@ class ElasticTrainer:
             return out_r, out_m, metrics
 
         jit_megabatch = jax.jit(
-            shard_map(
+            jax.shard_map(
                 timed_megabatch,
                 mesh=mesh,
                 # stacked batches/mask are (n_rounds, R, ...): dim 1 shards
                 in_specs=(s0, s0, s1, s0, s1),
                 # the psum-ed scalar metrics are replicated on every shard
                 out_specs=(s0, s0, P()),
-                check_rep=False,
+                check_vma=False,
             ),
             donate_argnums=donate,
         )
@@ -625,24 +624,36 @@ class ElasticTrainer:
             # its (R_local, ...) broadcast, reassembled to the full replica
             # tree. globals/prev ride in replicated; None pytrees are empty
             # and match the P() prefix spec trivially.
-            return shard_map(
+            return jax.shard_map(
                 functools.partial(merge_fn, gamma=gamma),
                 mesh=mesh,
                 in_specs=(s0, s0, P(), P()),
                 out_specs=(P(), s0),
-                check_rep=False,
+                check_vma=False,
             )(replicas, alphas, global_model, prev_global)
 
         norms = jax.jit(
-            shard_map(
+            jax.shard_map(
                 tu.tree_l2_norm_per_replica,
                 mesh=mesh,
                 in_specs=(s0,),
                 out_specs=s0,
-                check_rep=False,
+                check_vma=False,
             )
         )
-        return _round, _megabatch, merge_sharded, norms
+        # The global model is replicated over the mesh, and the compiler
+        # cannot partition a Pallas kernel (the XML input layer on a TPU):
+        # every shard evaluates the whole test batch.
+        evaluate = jax.jit(
+            jax.shard_map(
+                loss_fn,
+                mesh=mesh,
+                in_specs=(P(), P()),
+                out_specs=P(),
+                check_vma=False,
+            )
+        )
+        return _round, _megabatch, merge_sharded, norms, evaluate
 
     def compile_cache_size(self) -> int:
         """Total compiled-variant count across every engine executor built
@@ -657,14 +668,29 @@ class ElasticTrainer:
             cache_size = getattr(inner, "_cache_size", None)
             return int(cache_size()) if cache_size is not None else 0
 
-        fns = [self._eval]
         if self._exec_cache:
-            for execs in self._exec_cache.values():
-                fns.extend(execs)
+            fns = [f for execs in self._exec_cache.values() for f in execs]
         else:
-            fns.extend([self._round, self._megabatch, self._merge,
-                        self._norms])
+            fns = [self._round, self._megabatch, self._merge, self._norms,
+                   self._eval]
         return sum(size(f) for f in fns)
+
+    def lower_megabatch(self, state: ElasticState, n_rounds: int):
+        """Lower the scan engine's mega-batch program for ``state``'s
+        population and ``n_rounds`` rounds of b_max slots, without running
+        it. ``state`` leaves may be arrays or ``jax.ShapeDtypeStruct``s.
+        ``.compile()`` of the result gives the program's device memory
+        (``memory_analysis()``) and its text — what a run would compile."""
+        R = self.cfg.n_replicas
+        spec = self.provider.staging_spec(n_rounds, R, self.cfg.b_max)
+        batches = {k: jax.ShapeDtypeStruct(s, d) for k, (s, d) in spec.items()}
+        lr = jax.ShapeDtypeStruct((R,), jnp.float32)
+        mask = jax.ShapeDtypeStruct((n_rounds, R), jnp.float32)
+        args = (state.replicas, state.momentum, batches, lr, mask)
+        sharded = getattr(self._megabatch, "_jit", None)
+        if sharded is not None:
+            return sharded.lower(*args)
+        return self._megabatch.lower(*args, transforms=self._transforms)
 
     # ------------------------------------------------------------------
     # jitted tensor math exposed to Algorithm.merge implementations

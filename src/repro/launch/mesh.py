@@ -12,6 +12,7 @@ Target hardware: TPU v5e pods — 256 chips/pod in a 16x16 mesh
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # v5e hardware constants (used by the roofline analysis)
 PEAK_FLOPS_BF16 = 197e12     # per chip
@@ -20,17 +21,24 @@ ICI_BW = 50e9                # bytes/s per link (~4 links/chip on the 2D torus)
 HBM_PER_CHIP = 16e9          # bytes
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all Auto: the sharding rules annotate with
+    constraints and let the compiler propagate (``jax.make_mesh`` now
+    defaults to Explicit axes, which type-check every op's sharding)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, multi_pod: bool = False):
     """Small mesh for CPU tests (requires host-device-count >= product)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_replica_mesh(n_replicas: int, devices=None, multihost=None):

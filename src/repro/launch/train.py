@@ -41,6 +41,8 @@ import argparse
 import json
 import os
 
+import jax
+
 from repro.configs.archs import ARCHS
 from repro.configs.base import ElasticConfig
 from repro.core import algorithms
@@ -53,6 +55,23 @@ from repro.models import model as MDL
 from repro.models.xml_mlp import XMLMLPConfig, make_model as make_xml_model
 from repro.optim.sgd import SGDConfig
 from repro.utils.logging import log
+
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_persistent_compilation_cache() -> str:
+    """Keep compiled programs across processes. ``JAX_COMPILATION_CACHE_DIR``
+    wins when set (JAX reads it itself); otherwise the cache is the fixed
+    ``<repo>/.jax_cache``, since the directory is part of every entry's
+    key. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def parse_elastic_schedule(spec: str) -> dict[int, int]:
@@ -92,6 +111,7 @@ def build_xml_workload(args):
         n_features=args.features,
         n_classes=args.classes,
         avg_nnz=args.avg_nnz,
+        avg_labels=args.avg_labels,
         seed=args.seed,
     )
     train, test = train_test_split(ds, test_frac=0.2, seed=args.seed)
@@ -115,6 +135,7 @@ def build_lm_workload(args):
 
 
 def main(argv=None):
+    """Parse ``argv``, train, and return ``(state, metrics_log, trainer)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="lm", choices=["xml", "lm"])
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=list(ARCHS))
@@ -213,9 +234,11 @@ def main(argv=None):
     ap.add_argument("--features", type=int, default=4096)
     ap.add_argument("--classes", type=int, default=1024)
     ap.add_argument("--avg-nnz", type=int, default=64)
+    ap.add_argument("--avg-labels", type=int, default=3)
     ap.add_argument("--hidden", type=int, default=128)
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    use_persistent_compilation_cache()
 
     mh = None
     monitor = None
@@ -342,7 +365,7 @@ def main(argv=None):
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(mlog.records, f, indent=1)
-    return state, mlog
+    return state, mlog, trainer
 
 
 if __name__ == "__main__":

@@ -85,6 +85,16 @@ def tree_weighted_sum_replicas(a: PyTree, alphas) -> PyTree:
     return tree_map(leaf, a)
 
 
+def spans_shards(axis_name: str | None) -> bool:
+    """True when tracing over a replica mesh axis of more than one shard.
+
+    A collective over a single shard is the identity; leaving it out keeps
+    the one-device sharded program the vmap placement's program, op for op
+    (so both fuse, and round, the same way).
+    """
+    return axis_name is not None and jax.lax.axis_size(axis_name) > 1
+
+
 def replica_all_sum(x, axis_name: str | None = None):
     """Sum ``x`` over all shards of the replica mesh axis.
 
@@ -94,7 +104,7 @@ def replica_all_sum(x, axis_name: str | None = None):
     dim only covers this shard's replicas, and cross-replica math must
     psum the partials over the mesh axis.
     """
-    return x if axis_name is None else jax.lax.psum(x, axis_name)
+    return jax.lax.psum(x, axis_name) if spans_shards(axis_name) else x
 
 
 def tree_replica_mean_keepdims(a: PyTree, axis_name: str | None = None) -> PyTree:
@@ -108,7 +118,7 @@ def tree_replica_mean_keepdims(a: PyTree, axis_name: str | None = None) -> PyTre
 
     def leaf(l):
         m = jnp.mean(l.astype(jnp.float32), axis=0, keepdims=True)
-        if axis_name is not None:
+        if spans_shards(axis_name):
             m = jax.lax.pmean(m, axis_name)
         return m
 

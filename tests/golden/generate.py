@@ -4,11 +4,13 @@ Runs every seed algorithm x both engines x {sparse, dense} gradient paths
 on a small deterministic XML workload and records per-mega-batch losses
 plus merged-parameter fingerprints (per-leaf mean and L2 norm).
 
-The committed ``algorithms_seed.json`` was produced by the PRE-refactor
-trainer (the five-way ``if algo == ...`` branching at git tag of PR 2), so
-``tests/test_algorithms.py`` proves the pluggable-strategy refactor is
-numerically identical to the seed behavior. Regenerate only when the
-*intended* numerics change (and say so in the PR):
+The goldens were first recorded from the pre-refactor trainer (the
+five-way ``if algo == ...`` branching), so ``tests/test_algorithms.py``
+proves the pluggable-strategy refactor numerically identical to the seed
+behavior. They were regenerated once since, for the vectorized synthetic
+data generator and the installed JAX's PRNG default (``jax_version`` in the
+file records which JAX produced them). Regenerate only when the *intended*
+numerics change (and say so in the change):
 
     PYTHONPATH=src python tests/golden/generate.py
 
@@ -105,7 +107,8 @@ def run_case(algo: str, engine: str, sparse: bool) -> dict:
 
 
 def main():
-    golden = {"n_megabatches": N_MEGA, "cases": {}}
+    golden = {"n_megabatches": N_MEGA, "jax_version": jax.__version__,
+              "cases": {}}
     for algo in SEED_ALGOS:
         for engine in ENGINES:
             for sparse in (True, False):

@@ -325,7 +325,7 @@ def test_toy_algorithm_through_launcher():
     """--algorithm picks up registry plugins with no launcher edits."""
     from repro.launch import train as train_mod
 
-    state, mlog = train_mod.main([
+    state, mlog, _ = train_mod.main([
         "--workload", "xml", "--algorithm", "toy_halfstep", "--replicas", "2",
         "--megabatches", "1", "--mega-batch", "2", "--b-max", "16",
         "--samples", "256", "--features", "128", "--classes", "32",

@@ -32,6 +32,30 @@ class TestSynth:
             _, _, lab = ds.sample(i)
             assert len(lab) >= 1
 
+    def test_rows_are_sorted_unique_and_in_range(self, ds):
+        for i in range(ds.n_samples):
+            feats, vals, lab = ds.sample(i)
+            assert np.all(np.diff(feats) > 0)          # unique, ascending
+            assert feats.min() >= 0 and feats.max() < ds.n_features
+            assert np.all(vals > 0)
+            assert len(np.unique(lab)) == len(lab)      # no repeated label
+            assert np.all(np.diff(lab[1:]) > 0)         # companions sorted
+            assert lab.max() < ds.n_classes
+
+    def test_published_widths_keep_the_distribution(self):
+        """Amazon-670K widths generate vectorized: log-normal nnz around the
+        published mean, Zipf-biased feature ids, correlated label sets."""
+        from repro.data.xml_synth import AMAZON_670K
+
+        d = make_xml_dataset(n_samples=4096, seed=1, **AMAZON_670K)
+        assert (d.n_features, d.n_classes) == (135_909, 670_091)
+        assert 0.85 * 76 < d.avg_nnz() < 1.15 * 76
+        nnz = np.diff(d.indptr)
+        assert nnz.std() > 20                 # heavy per-sample variation
+        # Zipf bias: the lowest 1% of feature ids draw far more than 1%
+        assert np.mean(d.indices < d.n_features // 100) > 0.2
+        assert 3 < d.avg_labels() < 6
+
     def test_paper_like_descriptors(self):
         d = make_paper_like("amazon-670k", scale=0.002, n_samples=64)
         assert d.n_classes >= 64

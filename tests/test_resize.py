@@ -423,7 +423,7 @@ def test_grow_then_shrink_converges_within_5pct(case_ds):
 def test_launcher_elastic_schedule_end_to_end():
     from repro.launch import train as train_mod
 
-    state, mlog = train_mod.main([
+    state, mlog, _ = train_mod.main([
         "--workload", "xml", "--algorithm", "adaptive",
         "--elastic-schedule", "0:2,2:4,4:2",
         "--megabatches", "6", "--mega-batch", "4", "--b-max", "16",

@@ -5,18 +5,21 @@ virtual device count must be fixed before jax initializes.
 """
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 import textwrap
 
 import pytest
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.configs.archs import ARCHS
     from repro.launch import specs as SP
@@ -28,7 +31,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models import model as MDL
 
     cfg = ARCHS["llama3.2-1b"].reduced()
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
     ax = MeshAxes(cfg, mesh)
     R, B, S = 2, 4, 32
 
@@ -82,8 +86,8 @@ def test_sharded_train_round_matches_single_device():
     r = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo",
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+        cwd=REPO,
     )
     assert r.returncode == 0, r.stdout + r.stderr
     assert "SHARDED_INTEGRATION_OK" in r.stdout

@@ -94,12 +94,12 @@ def test_grad_equivalence_sweep(b, k, nf, h, dtype, block_k):
     np.testing.assert_allclose(_f32(gv_k), _f32(gv_r), **_tol(dtype))
 
 
-@pytest.mark.parametrize("block_h", [128, 512])
-def test_grad_w_standalone_vs_ref(block_h):
+@pytest.mark.parametrize("chunk", [8, 128, 512])
+def test_grad_w_standalone_vs_ref(chunk):
     b, k, nf, h = 4, 9, 200, 160
     fi, fv, fm = _batch(b, k, nf, duplicate=True)
     dh = jnp.asarray(RNG.normal(size=(b, h)), jnp.float32)
-    got = spmm_grad_w(fi, fv, fm, dh, nf, block_h=block_h)
+    got = spmm_grad_w(fi, fv, fm, dh, nf, chunk=chunk)
     want = spmm_grad_w_ref(fi, fv, fm, dh, nf)
     np.testing.assert_allclose(_f32(got), _f32(want), rtol=1e-5, atol=1e-5)
 

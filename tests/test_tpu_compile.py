@@ -1,0 +1,162 @@
+"""Rehearsal compiles: the main path's Pallas kernels, AOT-compiled for a
+described (not attached) TPU v5e at Amazon-670K widths.
+
+Interpret mode accepts block shapes and memory layouts that the TPU's
+Mosaic compiler refuses, so the CPU kernel tests alone cannot show that a
+kernel will run on the chip. Each test here lowers a kernel natively
+(``interpret=False``) against one device of a ``v5e:2x2`` topology
+description and asserts that the compiled program contains the Mosaic
+kernel (``tpu_custom_call``). Nothing runs, so results and times are not
+checked here.
+
+The topology is described only inside a module fixture: loading the TPU
+library while a module is imported would make pytest-xdist workers collect
+different tests.
+"""
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.data.xml_synth import AMAZON_670K
+from repro.kernels.spmm.ops import _fold_vmap
+from repro.kernels.spmm.spmm import spmm_grad_w_replicated, spmm_replicated
+from repro.kernels.weighted_merge.weighted_merge import weighted_merge
+
+NF = AMAZON_670K["n_features"]
+NC = AMAZON_670K["n_classes"]
+H = 128           # configs/archs.py XML_WORKLOADS hidden width
+B, K, R = 256, 256, 4  # b_max, padded nnz slots, replicas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda s, dtype: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one: keep the cache out of these tests."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _assert_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("replicas", [None, R], ids=["plain", "vmap_r4"])
+def test_spmm_compiles_for_v5e(shape, replicas):
+    """The forward gather, alone and under vmap over the replicas (the
+    trainer's round body), at the batch the trainer packs."""
+    call = _fold_vmap(functools.partial(spmm_replicated, interpret=False))
+    fn = lambda i, v, m, w: call(i[None], v[None], m[None], w[None])[0]
+    lead = () if replicas is None else (replicas,)
+    if replicas is not None:
+        fn = jax.vmap(fn)
+    _assert_kernel(
+        fn,
+        shape(lead + (B, K), jnp.int32),
+        shape(lead + (B, K), jnp.float32),
+        shape(lead + (B, K), jnp.bool_),
+        shape(lead + (NF, H), jnp.float32),
+    )
+
+
+def test_spmm_grad_w_compiles_for_v5e(shape):
+    fn = functools.partial(spmm_grad_w_replicated, n_rows=NF, interpret=False)
+    _assert_kernel(
+        fn,
+        shape((1, B, K), jnp.int32),
+        shape((1, B, K), jnp.float32),
+        shape((1, B, K), jnp.bool_),
+        shape((1, B, H), jnp.float32),
+    )
+
+
+def test_sharded_eval_compiles_for_v5e_mesh(topo, monkeypatch):
+    """Under --placement sharded the global model is replicated over a
+    four-chip replica mesh; the compiler cannot partition the model's
+    Pallas input layer, so the trainer's eval must run it per shard."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs.base import ElasticConfig
+    from repro.core.trainer import ElasticTrainer
+    from repro.data.providers import SparseProvider
+    from repro.data.xml_synth import make_xml_dataset
+    from repro.kernels.spmm import ops as spmm_ops
+    from repro.models.xml_mlp import XMLMLPConfig, make_model
+    from repro.optim.sgd import SGDConfig
+    from repro.sharding.rules import REPLICA_AXIS
+
+    # native kernels, as on the chip (the backend here is the CPU)
+    monkeypatch.setattr(spmm_ops, "_interpret_mode", lambda: False)
+    spmm_ops._spmm_call.cache_clear()
+    ds = make_xml_dataset(n_samples=256, n_features=2048, n_classes=512)
+    provider = SparseProvider.make(ds, seed=0)
+    model = make_model(XMLMLPConfig(n_features=2048, n_classes=512,
+                                    use_spmm_kernel=True))
+    mesh = Mesh(np.asarray(topo.devices[:R]), (REPLICA_AXIS,))
+    trainer = ElasticTrainer(
+        model=model, provider=provider, sgd=SGDConfig(), base_lr=0.05,
+        seed=0, mesh=mesh,
+        cfg=ElasticConfig.from_bmax(32, algorithm="adaptive", n_replicas=R,
+                                    placement="sharded"),
+    )
+    replicated = NamedSharding(mesh, P())
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=replicated),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+    )
+    batch = {
+        k: jax.ShapeDtypeStruct(s[2:], d, sharding=replicated)
+        for k, (s, d) in provider.staging_spec(1, 1, 32).items()
+    }
+    try:
+        text = trainer._eval.lower(params, batch).compile().as_text()
+    finally:
+        spmm_ops._spmm_call.cache_clear()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [NF * H, H * NC], ids=["w1", "w2"])
+def test_weighted_merge_momentum_compiles_for_v5e(shape, n):
+    """Algorithm 2's merge with the momentum term over R replicas of one
+    parameter leaf."""
+    fn = functools.partial(weighted_merge, gamma=0.9, interpret=False)
+    _assert_kernel(
+        fn,
+        shape((R, n), jnp.float32),
+        shape((R,), jnp.float32),
+        shape((n,), jnp.float32),
+        shape((n,), jnp.float32),
+    )
