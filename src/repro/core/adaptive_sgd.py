@@ -127,32 +127,33 @@ def normalized_merge(
     axis before the momentum term; every shard then holds the replicated
     new global. This is exactly the paper §4 all-reduce merge.
     """
-    alphas = jnp.asarray(alphas, jnp.float32)
-    if use_kernel is None:
-        use_kernel = jax.default_backend() in ("tpu", "gpu")
-    momentum = not (global_model is None or prev_global is None or gamma == 0.0)
-    if use_kernel:
-        from repro.kernels.weighted_merge.ops import merge_pytree
+    with jax.named_scope("merge"):
+        alphas = jnp.asarray(alphas, jnp.float32)
+        if use_kernel is None:
+            use_kernel = jax.default_backend() in ("tpu", "gpu")
+        momentum = not (global_model is None or prev_global is None or gamma == 0.0)
+        if use_kernel:
+            from repro.kernels.weighted_merge.ops import merge_pytree
 
-        if momentum and axis_name is None:
-            # single-program path: weighted sum + momentum fused in-kernel
-            return merge_pytree(replicas, alphas, global_model, prev_global, gamma)
-        merged = merge_pytree(replicas, alphas)
-    else:
-        merged = tu.tree_weighted_sum_replicas(replicas, alphas)
-    # per-shard partials -> the collective merge (momentum term must see
-    # the complete weighted sum, so the psum sits between the two)
-    merged = tu.tree_map(lambda l: tu.replica_all_sum(l, axis_name), merged)
-    if not momentum:
-        return merged
-    return tu.tree_map(
-        lambda m, g, gp: (
-            m.astype(jnp.float32) + gamma * (g.astype(jnp.float32) - gp.astype(jnp.float32))
-        ).astype(m.dtype),
-        merged,
-        global_model,
-        prev_global,
-    )
+            if momentum and axis_name is None:
+                # single-program path: weighted sum + momentum fused in-kernel
+                return merge_pytree(replicas, alphas, global_model, prev_global, gamma)
+            merged = merge_pytree(replicas, alphas)
+        else:
+            merged = tu.tree_weighted_sum_replicas(replicas, alphas)
+        # per-shard partials -> the collective merge (momentum term must see
+        # the complete weighted sum, so the psum sits between the two)
+        merged = tu.tree_map(lambda l: tu.replica_all_sum(l, axis_name), merged)
+        if not momentum:
+            return merged
+        return tu.tree_map(
+            lambda m, g, gp: (
+                m.astype(jnp.float32) + gamma * (g.astype(jnp.float32) - gp.astype(jnp.float32))
+            ).astype(m.dtype),
+            merged,
+            global_model,
+            prev_global,
+        )
 
 
 def replica_regularization(replicas: PyTree) -> np.ndarray:

@@ -78,7 +78,7 @@ from repro.models.protocol import TrainableModel, as_trainable_model
 from repro.optim.sgd import SGDConfig, init_momentum, sgd_update
 from repro.sharding.rules import REPLICA_AXIS, ReplicaMeshPool, replica_spec
 from repro.utils import tree as tu
-from repro.utils.logging import MetricsLog, log
+from repro.utils.logging import MetricsLog, log, span as trace_span
 
 PyTree = Any
 
@@ -139,6 +139,7 @@ class _StagedMegaBatch:
     n_replicas: int
     slot_id: Optional[int]    # StagingBuffers slot, None = unbuffered
     snapshot: dict            # pre-staging cursor state (see above)
+    counters: dict            # slot counts of the plan (``_slot_counters``)
 
 
 @dataclass
@@ -230,6 +231,7 @@ class ElasticTrainer:
         self._eval_batches_src = None    # pins the staged list + its batches
         self._eval_batches_key = None    # content fingerprint of that list
         self._staged = None              # prefetched _StagedMegaBatch
+        self._current_megabatch = 0      # index the host spans carry
         self._staging = StagingBuffers() # double-buffered host staging slots
         # per-shard measured timing (DESIGN.md §8): only the sharded
         # executors carry the debug-callback markers, and only a measured
@@ -762,16 +764,19 @@ class ElasticTrainer:
         )
         return merged, new_replicas
 
-    def replica_norms(self, replicas):
-        """Per-replica L2 norms (feeds Alg. 2's perturbation condition).
+    def replica_norms(self, replicas) -> np.ndarray:
+        """Per-replica L2 norms, read to the host (feeds Alg. 2's
+        perturbation condition).
         Host span: local norms are bit-exact per replica (no cross-replica
         reduction), so an allgather reassembles the global (R,) vector; a
         dead peer's rows read 0 — its merge weight is redistributed at the
         merge anyway."""
+        with trace_span("sync.norms", megabatch=self._current_megabatch):
+            local = np.asarray(self._norms(replicas))
         if self._span is None:
-            return self._norms(replicas)
+            return local
         span = self._span
-        local = np.asarray(self._norms(replicas), np.float64)
+        local = local.astype(np.float64)
         gathered = span.allgather("norms", local)
         out = np.zeros(self.cfg.n_replicas, np.float64)
         for pid, arr in gathered.items():
@@ -1126,24 +1131,30 @@ class ElasticTrainer:
         plans identically), but only this process's replica columns are
         uploaded and executed."""
         R = self.cfg.n_replicas
-        min_rounds = _next_pow2(plan.n_rounds) if self.round_bucket else plan.n_rounds
-        grid = plan.payload_grid(R, min_rounds=max(min_rounds, 1))
-        batches_np, mask = self.provider.stack_plan(grid, b_slots)
-        lr = np.asarray(state.lr, np.float32)
-        if self._span is not None:
-            sl = self._span_slice()
-            batches_np = {k: v[:, sl] for k, v in batches_np.items()}
-            mask = mask[:, sl]
-            lr = lr[sl]
-        batches = {k: jnp.asarray(v) for k, v in batches_np.items()}
-        replicas, momentum, m = self._megabatch(
-            state.replicas,
-            state.momentum,
-            batches,
-            jnp.asarray(lr),
-            jnp.asarray(mask),
-            transforms=transforms,
-        )
+        mb = int(state.megabatch_idx)
+        with trace_span("stage", megabatch=mb):
+            with trace_span("stage.plan", megabatch=mb):
+                grid = plan.payload_grid(R, min_rounds=self._bucket_rounds(plan))
+            with trace_span("stage.pack", megabatch=mb):
+                batches_np, mask = self.provider.stack_plan(grid, b_slots)
+            lr = np.asarray(state.lr, np.float32)
+            if self._span is not None:
+                sl = self._span_slice()
+                batches_np = {k: v[:, sl] for k, v in batches_np.items()}
+                mask = mask[:, sl]
+                lr = lr[sl]
+            with trace_span("stage.upload", megabatch=mb):
+                batches = {k: jnp.asarray(v) for k, v in batches_np.items()}
+                lr, mask = jnp.asarray(lr), jnp.asarray(mask)
+        with trace_span("dispatch", megabatch=mb):
+            replicas, momentum, m = self._megabatch(
+                state.replicas,
+                state.momentum,
+                batches,
+                lr,
+                mask,
+                transforms=transforms,
+            )
         # single host sync per mega-batch
         loss, acc = self._finish_metrics(m)
         return replicas, momentum, loss, acc
@@ -1199,6 +1210,7 @@ class ElasticTrainer:
         ``state`` as consumed and continue from the returned state only.
         (On CPU donation is disabled and old states stay readable.)
         """
+        self._current_megabatch = int(state.megabatch_idx)
         if self.overlap and self.engine == "scan":
             # prefetch is opt-in (run() and bench loops pass it): a bare
             # run_megabatch call must leave no dangling staged plan, so the
@@ -1220,12 +1232,15 @@ class ElasticTrainer:
         R = cfg.n_replicas
         mega_samples = cfg.mega_batch * cfg.b_max
         b_slots = cfg.b_max
+        mb = int(state.megabatch_idx)
 
         def fetch(i, take):
             payload = self.provider.fetch(take, b_slots)
             return payload, self.provider.work_units(payload)
 
-        plan = self.algo.plan(self.scheduler, state, mega_samples, fetch)
+        with trace_span("stage", megabatch=mb), \
+                trace_span("stage.plan", megabatch=mb):
+            plan = self.algo.plan(self.scheduler, state, mega_samples, fetch)
 
         # ---- execute lockstep rounds ----
         run_rounds = (
@@ -1262,18 +1277,20 @@ class ElasticTrainer:
                 guard_repaired = np.flatnonzero(~finite).tolist()
 
         # ---- merge (the barrier) + between-mega-batch adaptation ----
-        outcome = self.algo.merge(self, state, plan, replicas)
-        new_b, new_lr = self.algo.adapt(state, plan, cfg)
+        with trace_span("merge", megabatch=mb):
+            outcome = self.algo.merge(self, state, plan, replicas)
         alphas = (
             outcome.alphas if outcome.alphas is not None else np.full(R, 1.0 / R)
         )
-
-        # merge happens at the barrier and costs virtual time on every
-        # replica; the strategy decides how many merges a mega-batch incurs
-        # (per-round for eager synchronous schemes, once for barrier-only).
-        n_merges = self.algo.merges_per_megabatch(plan)
-        self.scheduler.clock.t[:] += self.merge_cost * n_merges
-        virtual_time = float(self.scheduler.clock.t.max())
+        with trace_span("adapt", megabatch=mb):
+            new_b, new_lr = self.algo.adapt(state, plan, cfg)
+            # merge happens at the barrier and costs virtual time on every
+            # replica; the strategy decides how many merges a mega-batch
+            # incurs (per-round for eager synchronous schemes, once for
+            # barrier-only).
+            n_merges = self.algo.merges_per_megabatch(plan)
+            self.scheduler.clock.t[:] += self.merge_cost * n_merges
+            virtual_time = float(self.scheduler.clock.t.max())
 
         new_state = ElasticState(
             replicas=outcome.replicas,
@@ -1295,10 +1312,38 @@ class ElasticTrainer:
             "train_accuracy": train_acc,
             "virtual_time": virtual_time,
             "n_rounds": plan.n_rounds,
+            **self._slot_counters(
+                plan,
+                plan.n_rounds if self.engine == "legacy_loop"
+                else self._bucket_rounds(plan),
+            ),
         }
         if guard_repaired:
             info["guard_repaired"] = guard_repaired
         return new_state, info
+
+    def _bucket_rounds(self, plan) -> int:
+        """Rounds the scan engine runs for ``plan``: its round count, padded
+        to a power of two with fully-masked rounds under ``round_bucket``."""
+        n = _next_pow2(plan.n_rounds) if self.round_bucket else plan.n_rounds
+        return max(n, 1)
+
+    def _slot_counters(self, plan, n_rounds: int) -> dict:
+        """What the device computes against what it holds, counted from the
+        plan: ``sample_slots`` (rounds × R × b_max) against ``samples``, and
+        for a provider with nnz slots (``max_nnz``) ``nnz_slots`` (samples ×
+        max_nnz) against ``nnz``, the dispatches' work units (Σ min(nnz_i,
+        max_nnz))."""
+        samples = sum(d.n_samples for d in plan.dispatches)
+        out = {
+            "sample_slots": n_rounds * self.cfg.n_replicas * self.cfg.b_max,
+            "samples": samples,
+        }
+        max_nnz = getattr(self.provider, "max_nnz", None)
+        if max_nnz is not None:
+            out["nnz_slots"] = samples * max_nnz
+            out["nnz"] = sum(d.work for d in plan.dispatches)
+        return out
 
     # ------------------------------------------------------------------
     # overlapped mega-batch pipeline (DESIGN.md §8)
@@ -1321,35 +1366,34 @@ class ElasticTrainer:
         """
         cfg = self.cfg
         R = cfg.n_replicas
+        mb = int(state.megabatch_idx)
         staged = self._take_staged(state)
         if staged is None:
-            staged = self._stage_megabatch(
-                state.b, state.lr, int(state.megabatch_idx)
-            )
+            staged = self._stage_megabatch(state.b, state.lr, mb)
         plan = staged.plan
 
         measure = isinstance(self.speed, MeasuredSpeedModel)
         t_start = self.speed.begin() if measure else None
         if measure and self._shard_timer is not None:
             self._shard_timer.reset(int(self.mesh.shape[REPLICA_AXIS]))
-        replicas, momentum, m = self._megabatch(
-            state.replicas,
-            state.momentum,
-            staged.batches,
-            staged.lr_dev,
-            staged.mask,
-            transforms=self._transforms,
-        )
+        with trace_span("dispatch", megabatch=mb):
+            replicas, momentum, m = self._megabatch(
+                state.replicas,
+                state.momentum,
+                staged.batches,
+                staged.lr_dev,
+                staged.mask,
+                transforms=self._transforms,
+            )
 
         # ---- host work overlapped with the in-flight device program ----
-        n_merges = self.algo.merges_per_megabatch(plan)
-        self.scheduler.clock.t[:] += self.merge_cost * n_merges
-        virtual_time = float(self.scheduler.clock.t.max())
-        new_b, new_lr = self.algo.adapt(state, plan, cfg)
+        with trace_span("adapt", megabatch=mb):
+            n_merges = self.algo.merges_per_megabatch(plan)
+            self.scheduler.clock.t[:] += self.merge_cost * n_merges
+            virtual_time = float(self.scheduler.clock.t.max())
+            new_b, new_lr = self.algo.adapt(state, plan, cfg)
         if prefetch:
-            self._staged = self._stage_megabatch(
-                new_b, new_lr, int(state.megabatch_idx) + 1
-            )
+            self._staged = self._stage_megabatch(new_b, new_lr, mb + 1)
 
         # ---- collect: the single host sync of the mega-batch ----
         train_loss, train_acc = self._finish_metrics(m)
@@ -1371,7 +1415,8 @@ class ElasticTrainer:
                 guard_repaired = np.flatnonzero(~finite).tolist()
 
         # ---- merge (the barrier) ----
-        outcome = self.algo.merge(self, state, plan, replicas)
+        with trace_span("merge", megabatch=mb):
+            outcome = self.algo.merge(self, state, plan, replicas)
         alphas = (
             outcome.alphas if outcome.alphas is not None else np.full(R, 1.0 / R)
         )
@@ -1396,6 +1441,7 @@ class ElasticTrainer:
             "train_accuracy": train_acc,
             "virtual_time": virtual_time,
             "n_rounds": plan.n_rounds,
+            **staged.counters,
         }
         if guard_repaired:
             info["guard_repaired"] = guard_repaired
@@ -1411,9 +1457,10 @@ class ElasticTrainer:
         only cross-process difference from the in-mesh psum path is float
         reassociation. A dead peer contributes nothing: that mega-batch's
         metrics cover the survivors."""
-        if "round_sums" not in m:
-            return float(m["loss"]), float(m["accuracy"])
-        sums = np.asarray(m["round_sums"], np.float32)
+        with trace_span("sync.metrics", megabatch=self._current_megabatch):
+            if "round_sums" not in m:
+                return float(m["loss"]), float(m["accuracy"])
+            sums = np.asarray(m["round_sums"], np.float32)
         if self._span is not None:
             total, _ = self._span.allreduce_sum("metrics", {"sums": sums})
             sums = np.asarray(total["sums"], np.float32)
@@ -1480,66 +1527,73 @@ class ElasticTrainer:
         staging revocable (``invalidate_prefetch``) and checkpoint-safe
         (``checkpoint_payload``).
         """
-        cfg = self.cfg
-        R = cfg.n_replicas
-        b_slots = cfg.b_max
-        mega_samples = cfg.mega_batch * cfg.b_max
-        b = np.asarray(b, np.float64).copy()
-        lr = np.asarray(lr, np.float64).copy()
-        snapshot = self._cursor_snapshot()
+        mb = int(megabatch_idx)
+        with trace_span("stage", megabatch=mb):
+            cfg = self.cfg
+            R = cfg.n_replicas
+            b_slots = cfg.b_max
+            mega_samples = cfg.mega_batch * cfg.b_max
+            b = np.asarray(b, np.float64).copy()
+            lr = np.asarray(lr, np.float64).copy()
+            snapshot = self._cursor_snapshot()
 
-        provider = self.provider
-        if hasattr(provider, "fetch_staged"):
-            def fetch(i, take):
-                return provider.fetch_staged(take, b_slots)
-        else:
-            def fetch(i, take):
-                payload = provider.fetch(take, b_slots)
-                return payload, provider.work_units(payload)
-
-        view = _PlanView(b=b, lr=lr, megabatch_idx=megabatch_idx)
-        plan = self.algo.plan(self.scheduler, view, mega_samples, fetch)
-        min_rounds = (
-            _next_pow2(plan.n_rounds) if self.round_bucket else plan.n_rounds
-        )
-        grid = plan.payload_grid(R, min_rounds=max(min_rounds, 1))
-
-        slot_id, out = None, None
-        if hasattr(provider, "staging_spec"):
-            spec = provider.staging_spec(len(grid), R, b_slots)
-            slot_id, out = self._staging.acquire(spec)
-            batches_np, mask = provider.stack_plan(grid, b_slots, out=out)
-        else:
-            batches_np, mask = provider.stack_plan(grid, b_slots)
-
-        lr32 = np.asarray(lr, np.float32)
-        if self._span is not None:
-            # host span: upload only this process's replica columns (the
-            # staging slot still packs the full global grid — its shapes
-            # key the double buffer; the slices below are views)
-            sl = self._span_slice()
-            batches_np = {k: v[:, sl] for k, v in batches_np.items()}
-            mask = mask[:, sl]
-            lr32 = lr32[sl]
-        if cfg.placement == "sharded":
-            s1 = NamedSharding(self.mesh, replica_spec(1))
-            s0 = NamedSharding(self.mesh, replica_spec(0))
-            if self._global_put:
-                batches = {k: self._put_leaf(v, s1) for k, v in batches_np.items()}
-                mask_dev = self._put_leaf(mask, s1)
-                lr_dev = self._put_leaf(lr32, s0)
+            provider = self.provider
+            if hasattr(provider, "fetch_staged"):
+                def fetch(i, take):
+                    return provider.fetch_staged(take, b_slots)
             else:
-                batches, mask_dev, lr_dev = jax.device_put(
-                    (batches_np, mask, lr32),
-                    ({k: s1 for k in batches_np}, s1, s0),
-                )
-        else:
-            batches, mask_dev, lr_dev = jax.device_put((batches_np, mask, lr32))
-        return _StagedMegaBatch(
-            plan=plan, batches=batches, mask=mask_dev, lr_dev=lr_dev,
-            b=b, lr=lr, megabatch_idx=int(megabatch_idx), n_replicas=R,
-            slot_id=slot_id, snapshot=snapshot,
-        )
+                def fetch(i, take):
+                    payload = provider.fetch(take, b_slots)
+                    return payload, provider.work_units(payload)
+
+            with trace_span("stage.plan", megabatch=mb):
+                view = _PlanView(b=b, lr=lr, megabatch_idx=megabatch_idx)
+                plan = self.algo.plan(self.scheduler, view, mega_samples, fetch)
+                grid = plan.payload_grid(R, min_rounds=self._bucket_rounds(plan))
+
+            with trace_span("stage.pack", megabatch=mb):
+                slot_id, out = None, None
+                if hasattr(provider, "staging_spec"):
+                    spec = provider.staging_spec(len(grid), R, b_slots)
+                    slot_id, out = self._staging.acquire(spec)
+                    batches_np, mask = provider.stack_plan(grid, b_slots, out=out)
+                else:
+                    batches_np, mask = provider.stack_plan(grid, b_slots)
+
+            lr32 = np.asarray(lr, np.float32)
+            if self._span is not None:
+                # host span: upload only this process's replica columns (the
+                # staging slot still packs the full global grid — its shapes
+                # key the double buffer; the slices below are views)
+                sl = self._span_slice()
+                batches_np = {k: v[:, sl] for k, v in batches_np.items()}
+                mask = mask[:, sl]
+                lr32 = lr32[sl]
+            with trace_span("stage.upload", megabatch=mb):
+                if cfg.placement == "sharded":
+                    s1 = NamedSharding(self.mesh, replica_spec(1))
+                    s0 = NamedSharding(self.mesh, replica_spec(0))
+                    if self._global_put:
+                        batches = {
+                            k: self._put_leaf(v, s1) for k, v in batches_np.items()
+                        }
+                        mask_dev = self._put_leaf(mask, s1)
+                        lr_dev = self._put_leaf(lr32, s0)
+                    else:
+                        batches, mask_dev, lr_dev = jax.device_put(
+                            (batches_np, mask, lr32),
+                            ({k: s1 for k in batches_np}, s1, s0),
+                        )
+                else:
+                    batches, mask_dev, lr_dev = jax.device_put(
+                        (batches_np, mask, lr32)
+                    )
+            return _StagedMegaBatch(
+                plan=plan, batches=batches, mask=mask_dev, lr_dev=lr_dev,
+                b=b, lr=lr, megabatch_idx=mb, n_replicas=R,
+                slot_id=slot_id, snapshot=snapshot,
+                counters=self._slot_counters(plan, len(grid)),
+            )
 
     def _take_staged(self, state: ElasticState) -> Optional[_StagedMegaBatch]:
         """Consume the prefetched mega-batch if it matches ``state`` —
@@ -1590,7 +1644,8 @@ class ElasticTrainer:
         need repair (and therefore issues the same repair exchanges); a
         dead peer's rows read finite — its weight is handled by eviction,
         not the guard."""
-        finite_local = np.asarray(self._finite_rows(replicas), bool)
+        with trace_span("sync.guard", megabatch=self._current_megabatch):
+            finite_local = np.asarray(self._finite_rows(replicas), bool)
         if self._span is None:
             return finite_local
         span = self._span
@@ -1708,18 +1763,21 @@ class ElasticTrainer:
         boundary and collects at the next one, so eval device work queues
         behind (and interleaves with) the next mega-batch instead of
         stalling the host between them."""
-        pending = [
-            self._eval(params, batch)
-            for batch in self._staged_test_batches(test_batches)
-        ]
+        mb = self._current_megabatch
+        with trace_span("eval.dispatch", megabatch=mb):
+            pending = [
+                self._eval(params, batch)
+                for batch in self._staged_test_batches(test_batches)
+            ]
 
         def collect() -> dict:
             tot_acc, tot_loss, tot_n = 0.0, 0.0, 0.0
-            for loss, aux in pending:
-                n = float(aux["n_valid"])
-                tot_acc += float(aux["accuracy"]) * n
-                tot_loss += float(loss) * n
-                tot_n += n
+            with trace_span("sync.eval", megabatch=mb):
+                for loss, aux in pending:
+                    n = float(aux["n_valid"])
+                    tot_acc += float(aux["accuracy"]) * n
+                    tot_loss += float(loss) * n
+                    tot_n += n
             return {
                 "accuracy": tot_acc / max(tot_n, 1.0),
                 "loss": tot_loss / max(tot_n, 1.0),
@@ -2065,9 +2123,11 @@ class ElasticTrainer:
         t0 = time.perf_counter()
         for mb in range(int(state.megabatch_idx), n_megabatches):
             if resize_schedule is not None and mb in resize_schedule:
-                state = self.resize(state, resize_schedule[mb])
+                with trace_span("resize", megabatch=mb):
+                    state = self.resize(state, resize_schedule[mb])
             if fleet is not None:
-                state = fleet.step(self, state, mb)
+                with trace_span("fleet", megabatch=mb):
+                    state = fleet.step(self, state, mb)
             # the final mega-batch stages nothing: run() must end with every
             # host cursor consumed (no dangling prefetch in checkpoints or
             # for a caller that continues this trainer by hand)
@@ -2075,7 +2135,8 @@ class ElasticTrainer:
                 state, prefetch=overlap_active and (mb + 1 < n_megabatches)
             )
             if checkpoint is not None:
-                checkpoint.maybe_save(self, state)
+                with trace_span("checkpoint", megabatch=mb):
+                    checkpoint.maybe_save(self, state)
             # collect the PREVIOUS boundary's async eval only now — its
             # device work ran behind this mega-batch instead of serializing
             drain_eval()

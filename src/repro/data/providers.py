@@ -47,6 +47,11 @@ class SparseProvider:
     def empty(self, b_slots: int) -> SparseBatch:
         return self.batcher.empty(b_slots)
 
+    @property
+    def max_nnz(self) -> int:
+        """nnz slots per sample; work units count the valid ones."""
+        return self.batcher.max_nnz
+
     def work_units(self, payload: SparseBatch) -> int:
         return payload.total_nnz
 

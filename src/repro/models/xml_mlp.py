@@ -67,15 +67,16 @@ def init_params(cfg: XMLMLPConfig, rng: jax.Array) -> dict:
 
 def _input_layer(cfg: XMLMLPConfig, w1: jax.Array, batch: dict) -> jax.Array:
     """The sparse input layer: h_lin (B, hidden)."""
-    if _kernel_routed(cfg):
-        from repro.kernels.spmm import ops as spmm_ops
+    with jax.named_scope("input_layer"):
+        if _kernel_routed(cfg):
+            from repro.kernels.spmm import ops as spmm_ops
 
-        return spmm_ops.spmm(
+            return spmm_ops.spmm(
+                batch["feat_idx"], batch["feat_val"], batch["feat_mask"], w1
+            )
+        return _sparse_input_ref(
             batch["feat_idx"], batch["feat_val"], batch["feat_mask"], w1
         )
-    return _sparse_input_ref(
-        batch["feat_idx"], batch["feat_val"], batch["feat_mask"], w1
-    )
 
 
 def _sparse_input_ref(feat_idx, feat_val, feat_mask, w1):
@@ -92,24 +93,25 @@ def _head_loss(h_lin: jax.Array, rest: dict, batch: dict):
     sample = mean over its true labels of -log p(label); batch loss is
     averaged over *valid* samples only (adaptive batch size).
     """
-    h = jax.nn.relu(h_lin + rest["b1"])
-    logits = (h @ rest["w2"] + rest["b2"]).astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    lab_logp = jnp.take_along_axis(logp, batch["label_idx"], axis=-1)
-    lmask = batch["label_mask"].astype(jnp.float32)
-    per_sample = -jnp.sum(lab_logp * lmask, axis=-1) / jnp.maximum(
-        jnp.sum(lmask, axis=-1), 1.0
-    )
-    smask = batch["sample_mask"].astype(jnp.float32)
-    n_valid = jnp.sum(smask)
-    loss = jnp.sum(per_sample * smask) / jnp.maximum(n_valid, 1.0)
+    with jax.named_scope("head"):
+        h = jax.nn.relu(h_lin + rest["b1"])
+        logits = (h @ rest["w2"] + rest["b2"]).astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        lab_logp = jnp.take_along_axis(logp, batch["label_idx"], axis=-1)
+        lmask = batch["label_mask"].astype(jnp.float32)
+        per_sample = -jnp.sum(lab_logp * lmask, axis=-1) / jnp.maximum(
+            jnp.sum(lmask, axis=-1), 1.0
+        )
+        smask = batch["sample_mask"].astype(jnp.float32)
+        n_valid = jnp.sum(smask)
+        loss = jnp.sum(per_sample * smask) / jnp.maximum(n_valid, 1.0)
 
-    pred = jnp.argmax(logits, axis=-1)
-    hit = jnp.any(
-        (batch["label_idx"] == pred[:, None]) & batch["label_mask"], axis=-1
-    ).astype(jnp.float32)
-    acc = jnp.sum(hit * smask) / jnp.maximum(n_valid, 1.0)
-    return loss, {"accuracy": acc, "n_valid": n_valid}
+        pred = jnp.argmax(logits, axis=-1)
+        hit = jnp.any(
+            (batch["label_idx"] == pred[:, None]) & batch["label_mask"], axis=-1
+        ).astype(jnp.float32)
+        acc = jnp.sum(hit * smask) / jnp.maximum(n_valid, 1.0)
+        return loss, {"accuracy": acc, "n_valid": n_valid}
 
 
 def forward(cfg: XMLMLPConfig, params: dict, batch: dict) -> jax.Array:
@@ -142,16 +144,17 @@ def loss_and_sparse_grad(cfg: XMLMLPConfig, params: dict, batch: dict):
     )
     dh, drest = head_vjp(jnp.ones_like(loss))
 
-    scale = (batch["feat_val"] * batch["feat_mask"]).astype(jnp.float32)
-    b, k = scale.shape
-    vals = scale[..., None] * dh.astype(jnp.float32)[:, None, :]  # (B, K, H)
-    rows = jnp.where(
-        batch["feat_mask"], batch["feat_idx"], cfg.n_features
-    ).astype(jnp.int32)
-    grads = dict(drest)
-    grads["w1"] = RowSparseGrad(
-        rows.reshape(b * k), vals.reshape(b * k, -1), cfg.n_features
-    )
+    with jax.named_scope("input_layer"):
+        scale = (batch["feat_val"] * batch["feat_mask"]).astype(jnp.float32)
+        b, k = scale.shape
+        vals = scale[..., None] * dh.astype(jnp.float32)[:, None, :]  # (B, K, H)
+        rows = jnp.where(
+            batch["feat_mask"], batch["feat_idx"], cfg.n_features
+        ).astype(jnp.int32)
+        grads = dict(drest)
+        grads["w1"] = RowSparseGrad(
+            rows.reshape(b * k), vals.reshape(b * k, -1), cfg.n_features
+        )
     return (loss, aux), grads
 
 

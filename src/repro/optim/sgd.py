@@ -198,11 +198,13 @@ def sgd_update(
     new_p, new_m = [], []
     for p, g, m in zip(p_leaves, g_leaves, m_leaves):
         if is_row_sparse(g):
-            np_, nm_ = _sparse_leaf_update(
-                p, g, m, lr, cfg, update_mask, replica_dim
-            )
+            with jax.named_scope("sparse_update"):
+                np_, nm_ = _sparse_leaf_update(
+                    p, g, m, lr, cfg, update_mask, replica_dim
+                )
         else:
-            np_, nm_ = _dense_leaf_update(p, g, m, lr, cfg, update_mask)
+            with jax.named_scope("dense_update"):
+                np_, nm_ = _dense_leaf_update(p, g, m, lr, cfg, update_mask)
         new_p.append(np_)
         new_m.append(nm_)
 

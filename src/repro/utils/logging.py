@@ -1,11 +1,15 @@
-"""Minimal structured logger + metrics accumulator for training loops."""
+"""Minimal structured logger, metrics accumulator and profiler spans for
+training loops."""
 from __future__ import annotations
 
 import json
 import sys
-import time
 from dataclasses import dataclass, field
 from typing import Any
+
+import jax
+
+SPAN_PREFIX = "repro."
 
 
 def log(msg: str, **kv: Any) -> None:
@@ -13,17 +17,22 @@ def log(msg: str, **kv: Any) -> None:
     print("[repro] " + " ".join(parts), file=sys.stderr, flush=True)
 
 
+def span(name: str, **args: Any) -> jax.profiler.TraceAnnotation:
+    """A host span ``repro.<name>`` on the profiler's clock, carrying
+    ``args`` (every trainer span carries ``megabatch=<index>``). Recorded
+    only inside an active profiler session (``jax.profiler.trace``); a
+    no-op otherwise. A span named ``sync.*`` blocks on a device result."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
+
+
 @dataclass
 class MetricsLog:
     """Append-only metrics log; one record per merge boundary / eval point."""
 
     records: list[dict] = field(default_factory=list)
-    _t0: float = field(default_factory=time.perf_counter)
 
     def append(self, **kv: Any) -> None:
-        rec = dict(kv)
-        rec.setdefault("wall_s", time.perf_counter() - self._t0)
-        self.records.append(rec)
+        self.records.append(dict(kv))
 
     def column(self, key: str) -> list:
         return [r[key] for r in self.records if key in r]
