@@ -243,6 +243,15 @@ class ElasticTrainer:
             and isinstance(self.speed, MeasuredSpeedModel)
             else None
         )
+        # rows w1 is stored with beyond the model's features (DESIGN.md
+        # §3), from the parameters' shapes alone: no device work
+        n_features = getattr(self.model.config, "n_features", None)
+        self._w1_pad_rows = None
+        if n_features is not None:
+            shapes = jax.eval_shape(
+                lambda: self.model.init(jax.random.PRNGKey(0))
+            )
+            self._w1_pad_rows = shapes["w1"].shape[0] - n_features
         self._build_jits()
 
     # ------------------------------------------------------------------
@@ -1333,7 +1342,8 @@ class ElasticTrainer:
         plan: ``sample_slots`` (rounds × R × b_max) against ``samples``, and
         for a provider with nnz slots (``max_nnz``) ``nnz_slots`` (samples ×
         max_nnz) against ``nnz``, the dispatches' work units (Σ min(nnz_i,
-        max_nnz))."""
+        max_nnz)). For a model with ``n_features``, ``w1_pad_rows``: the
+        zero rows w1 is stored with beyond them (a constant)."""
         samples = sum(d.n_samples for d in plan.dispatches)
         out = {
             "sample_slots": n_rounds * self.cfg.n_replicas * self.cfg.b_max,
@@ -1343,6 +1353,8 @@ class ElasticTrainer:
         if max_nnz is not None:
             out["nnz_slots"] = samples * max_nnz
             out["nnz"] = sum(d.work for d in plan.dispatches)
+        if self._w1_pad_rows is not None:
+            out["w1_pad_rows"] = self._w1_pad_rows
         return out
 
     # ------------------------------------------------------------------

@@ -20,6 +20,7 @@ oracle.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -50,11 +51,45 @@ def _kernel_routed(cfg: XMLMLPConfig) -> bool:
     return cfg.use_spmm_kernel
 
 
+def stored_rows(n_rows: int, dtype) -> int:
+    """``n_rows`` rounded up to ``dtype``'s sublane tile (32 // itemsize:
+    8 rows of float32, 16 of bfloat16): the row count w1 is stored with.
+
+    On a TPU the replica-batched w1 scatter is one scatter over the
+    flattened (R * rows, H) operand; with ``rows`` a whole number of tiles
+    that flatten is a bitcast and the update runs in place on the scan's
+    carry; any other count costs three whole-w1 passes a round (DESIGN.md
+    §3).
+    """
+    tile = 32 // jnp.dtype(dtype).itemsize
+    return -(-n_rows // tile) * tile
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _zero_rows_from(w: jax.Array, n: int) -> jax.Array:
+    """``w`` with its rows from ``n`` on set to zero."""
+    keep = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) < n
+    return jnp.where(keep, w, jnp.zeros_like(w))
+
+
 def init_params(cfg: XMLMLPConfig, rng: jax.Array) -> dict:
-    """Paper: weights ~ Normal with std scaled by layer width."""
+    """Paper: weights ~ Normal with std scaled by layer width.
+
+    w1 has ``stored_rows(n_features, dtype)`` rows: the first
+    ``n_features`` are the model's, the rest zero. No feature id reaches
+    the padding rows, so they are never gathered nor updated.
+    """
     k1, k2 = jax.random.split(rng)
-    w1 = jax.random.normal(k1, (cfg.n_features, cfg.hidden), cfg.dtype)
+    rows = stored_rows(cfg.n_features, cfg.dtype)
+    # Drawn at the stored shape and masked, not drawn and padded: with
+    # JAX's partitionable threefry an element's draw depends on its index
+    # alone, so the first n_features rows equal an unpadded draw bit for
+    # bit, and a jitted init fuses the draw into its consumer (a pad stops
+    # that and doubles the TPU program's code).
+    w1 = jax.random.normal(k1, (rows, cfg.hidden), cfg.dtype)
     w1 = w1 * (1.0 / jnp.sqrt(cfg.n_features))
+    if rows > cfg.n_features:
+        w1 = _zero_rows_from(w1, cfg.n_features)
     w2 = jax.random.normal(k2, (cfg.hidden, cfg.n_classes), cfg.dtype)
     w2 = w2 * (1.0 / jnp.sqrt(cfg.hidden))
     return {
@@ -135,7 +170,8 @@ def loss_and_sparse_grad(cfg: XMLMLPConfig, params: dict, batch: dict):
     analytically ``dW[idx[b,k]] += scale[b,k] * dh[b]`` — exactly the
     RowSparseGrad layout, so we pull ``dh`` back through the head with
     jax.vjp and never build the dense (NF, H) gradient. Masked/padded nnz
-    slots get the out-of-bounds sentinel row NF (scatter drops them).
+    slots get the out-of-bounds sentinel row: w1's *stored* row count
+    (``stored_rows``), which the scatter drops.
     """
     rest = {k: v for k, v in params.items() if k != "w1"}
     h_lin = _input_layer(cfg, params["w1"], batch)
@@ -148,12 +184,13 @@ def loss_and_sparse_grad(cfg: XMLMLPConfig, params: dict, batch: dict):
         scale = (batch["feat_val"] * batch["feat_mask"]).astype(jnp.float32)
         b, k = scale.shape
         vals = scale[..., None] * dh.astype(jnp.float32)[:, None, :]  # (B, K, H)
+        n_rows = params["w1"].shape[0]
         rows = jnp.where(
-            batch["feat_mask"], batch["feat_idx"], cfg.n_features
+            batch["feat_mask"], batch["feat_idx"], n_rows
         ).astype(jnp.int32)
         grads = dict(drest)
         grads["w1"] = RowSparseGrad(
-            rows.reshape(b * k), vals.reshape(b * k, -1), cfg.n_features
+            rows.reshape(b * k), vals.reshape(b * k, -1), n_rows
         )
     return (loss, aux), grads
 

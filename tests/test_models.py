@@ -1,5 +1,8 @@
 """Model-internals correctness: SSD vs naive recurrence, decode==forward,
-blockwise attention vs dense reference, MoE dispatch invariants."""
+blockwise attention vs dense reference, MoE dispatch invariants, the XML
+model's stored w1 rows."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,3 +162,95 @@ class TestMoE:
 
         g = jax.grad(loss)(p)
         assert np.abs(np.asarray(g["router"])).sum() > 0
+
+
+class TestXMLStoredRows:
+    """w1 is stored with its row count rounded up to the float32 sublane
+    tile (DESIGN.md §3); the padding rows are inert."""
+
+    H, R = 16, 4
+
+    @staticmethod
+    def _batches(nf, n_rounds, b_slots=8):
+        from repro.data.providers import SparseProvider
+        from repro.data.xml_synth import make_xml_dataset
+
+        ds = make_xml_dataset(n_samples=256, n_features=nf, n_classes=32,
+                              avg_nnz=16, seed=0)
+        prov = SparseProvider.make(ds, seed=1)
+        # every replica's batch leaves sample slots (and so nnz slots) masked
+        return [
+            {k: jnp.asarray(v) for k, v in prov.stack(
+                [prov.fetch(b_slots - 1 - r, b_slots) for r in range(4)]
+            ).items()}
+            for _ in range(n_rounds)
+        ]
+
+    @pytest.mark.parametrize("nf", [512, 509], ids=["aligned", "unaligned"])
+    def test_init_pads_w1_with_zero_rows(self, nf):
+        from repro.models.xml_mlp import XMLMLPConfig, init_params, stored_rows
+
+        cfg = XMLMLPConfig(n_features=nf, n_classes=32, hidden=self.H)
+        assert stored_rows(nf, jnp.float32) == -(-nf // 8) * 8
+        assert stored_rows(nf, jnp.bfloat16) == -(-nf // 16) * 16
+        key = jax.random.PRNGKey(7)
+        w1 = np.asarray(init_params(cfg, key)["w1"])
+        assert w1.shape == (-(-nf // 8) * 8, self.H)
+        k1, _ = jax.random.split(key)
+        want = jax.random.normal(k1, (nf, self.H)) * (1.0 / jnp.sqrt(nf))
+        np.testing.assert_array_equal(w1[:nf], np.asarray(want))
+        assert not w1[nf:].any()
+
+    @pytest.mark.parametrize("nf", [512, 509], ids=["aligned", "unaligned"])
+    def test_padding_rows_stay_zero_and_sparse_matches_dense(self, nf):
+        """Three R=4 rounds of the sparse path (one replica masked out in
+        the second) against the dense ``loss_fn`` oracle."""
+        from repro.models.xml_mlp import (
+            XMLMLPConfig, init_params, loss_and_sparse_grad, loss_fn,
+        )
+        from repro.optim.sgd import SGDConfig, sgd_update
+        import repro.utils.tree as tu
+
+        cfg = XMLMLPConfig(n_features=nf, n_classes=32, hidden=self.H)
+        reps = tu.tree_broadcast_replicas(
+            init_params(cfg, jax.random.PRNGKey(0)), self.R)
+        lr = jnp.asarray([0.5, 0.4, 0.3, 0.2])
+
+        @functools.partial(jax.jit, static_argnums=3)
+        def step(p, batch, mask, sparse):
+            if sparse:
+                (loss, _), g = jax.vmap(
+                    lambda q, b: loss_and_sparse_grad(cfg, q, b))(p, batch)
+            else:
+                (loss, _), g = jax.vmap(jax.value_and_grad(
+                    lambda q, b: loss_fn(cfg, q, b), has_aux=True))(p, batch)
+            new, _ = sgd_update(p, g, lr, SGDConfig(), update_mask=mask,
+                                replica_dim=True)
+            return new, loss
+
+        batches = self._batches(nf, 3)
+        # masked nnz slots point one past the *stored* rows: dropped by the
+        # scatter, and the gradient densifies to w1's stored shape
+        g = loss_and_sparse_grad(
+            cfg, tu.tree_replica_slice(reps, 0),
+            {k: v[0] for k, v in batches[0].items()})[1]["w1"]
+        masked = ~np.asarray(batches[0]["feat_mask"][0]).reshape(-1)
+        assert masked.any()
+        assert (np.asarray(g.rows)[masked] == reps["w1"].shape[1]).all()
+        assert g.densify().shape == reps["w1"].shape[1:]
+        masks = [jnp.ones(self.R), jnp.asarray([1.0, 0.0, 1.0, 1.0]),
+                 jnp.ones(self.R)]
+        runs = {}
+        for sparse in (True, False):
+            p, losses = reps, []
+            for batch, mask in zip(batches, masks):
+                p, loss = step(p, batch, mask, sparse)
+                losses.append(np.asarray(loss))
+            runs[sparse] = (np.asarray(p["w1"]), np.stack(losses))
+        (w_s, l_s), (w_d, l_d) = runs[True], runs[False]
+        assert w_s.shape == (self.R, -(-nf // 8) * 8, self.H)
+        assert not w_s[:, nf:].any() and not w_d[:, nf:].any()
+        assert not np.array_equal(w_s[:, :nf], np.asarray(reps["w1"])[:, :nf])
+        np.testing.assert_allclose(l_s, l_d, rtol=2e-4, atol=1e-5)
+        np.testing.assert_allclose(w_s[:, :nf], w_d[:, :nf], rtol=1e-4,
+                                   atol=1e-5)

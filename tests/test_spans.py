@@ -8,7 +8,8 @@ counters of each mega-batch's record.
 * scopes — the lowered mega-batch, merge and eval programs name the input
   layer, the head, the sparse and dense updates and the merge;
 * counters — ``sample_slots`` / ``samples`` / ``nnz_slots`` / ``nnz`` in
-  ``info`` agree with the plan, on both mega-batch paths;
+  ``info`` agree with the plan, on both mega-batch paths; ``w1_pad_rows``
+  with w1's stored shape;
 * cost — the arithmetic does not change: a traced run is bit-identical to
   an untraced one.
 """
@@ -142,6 +143,7 @@ def test_slot_counters_follow_the_plan(split, overlap):
         assert info["samples"] <= info["sample_slots"]
         assert info["nnz_slots"] == info["samples"] * k
         assert 0 < info["nnz"] <= info["nnz_slots"]
+        assert info["w1_pad_rows"] == 0  # 512 features: a whole tile
 
 
 def test_slot_counters_match_across_paths(split):
@@ -151,7 +153,7 @@ def test_slot_counters_match_across_paths(split):
         tr = build_case_trainer("adaptive", "scan", True, train)
         tr.overlap = overlap
         _, mlog = tr.run(3)
-        keys = ("sample_slots", "samples", "nnz_slots", "nnz")
+        keys = ("sample_slots", "samples", "nnz_slots", "nnz", "w1_pad_rows")
         return [{k: r[k] for k in keys} for r in mlog.records]
 
     assert records(True) == records(False)
@@ -176,6 +178,29 @@ def test_token_provider_reports_no_nnz_counters():
     _, info = tr.run_megabatch(tr.init_state())
     assert info["samples"] == 3 * 8
     assert "nnz" not in info and "nnz_slots" not in info
+    assert "w1_pad_rows" not in info
+
+
+@pytest.mark.parametrize("nf,pad", [(512, 0), (509, 3)],
+                         ids=["aligned", "unaligned"])
+def test_w1_pad_rows_reads_the_stored_shape(nf, pad):
+    from repro.configs.base import ElasticConfig
+    from repro.core.trainer import ElasticTrainer
+    from repro.data.providers import SparseProvider
+    from repro.data.xml_synth import make_xml_dataset
+    from repro.models.xml_mlp import XMLMLPConfig, make_model
+
+    ds = make_xml_dataset(n_samples=256, n_features=nf, n_classes=32,
+                          avg_nnz=16, seed=0)
+    tr = ElasticTrainer(
+        make_model(XMLMLPConfig(n_features=nf, n_classes=32, hidden=16)),
+        SparseProvider.make(ds, seed=0),
+        ElasticConfig.from_bmax(8, algorithm="adaptive", n_replicas=2,
+                                mega_batch=3),
+        base_lr=0.1, seed=0,
+    )
+    state, info = tr.run_megabatch(tr.init_state())
+    assert info["w1_pad_rows"] == pad == state.replicas["w1"].shape[1] - nf
 
 
 def test_metrics_log_records_no_wall_stamp(split):
