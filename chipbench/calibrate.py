@@ -4,8 +4,8 @@ size, in one process:
 * the program on many seeds (each limit's lower reading is the largest);
 * the control, the reference computed in bfloat16 in the program's place,
   on a few seeds;
-* each fault the cell can have (``faults.py``), planted under the timed
-  path, on a few seeds.
+* each fault the cell can have (``faults.py``, or its family's own),
+  planted under the timed path, on a few seeds.
 
     python3 chipbench/calibrate.py --workload <cell> --seeds 12 --few 3
 
@@ -87,7 +87,7 @@ def main(argv=None):
             readings["program"].append(run("program", seed, controls=controls))
         for name in names:
             for i in range(args.few):
-                with faults.FAULTS[name]():
+                with faults.plant(name, cell["family"]):
                     readings[name].append(run(name, FIRST_SEED + 100 + i))
     summary = {}
     for number in compare.NAMES:

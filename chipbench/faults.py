@@ -4,17 +4,17 @@ limit's upper end (``calibrate.py``, on the chip) and for the test that sees
 
 Each is a context manager that patches the program for the trainers built
 inside it. Every trainer builds its jitted programs anew, so a trainer built
-inside the context traces the patched code.
+inside the context traces the patched code. A fault that breaks a part
+particular to one model family, such as where its batches are packed, is a
+function of that name in the family's module (``families/<family>.py``).
 """
 from __future__ import annotations
 
 import contextlib
 
-import numpy as np
-
 
 @contextlib.contextmanager
-def _patched(module, name, replacement):
+def patched(module, name, replacement):
     original = getattr(module, name)
     setattr(module, name, replacement(original))
     try:
@@ -32,25 +32,7 @@ def unchanged_state():
             return params, momentum_state
         return sgd_update
 
-    return _patched(trainer, "sgd_update", replacement)
-
-
-def half_batch():
-    """Each packed batch keeps only the first half of its valid samples;
-    the loss is then the mean over the rest."""
-    from repro.data import providers
-
-    def replacement(original):
-        def stack_lazy_plan(*args, **kwargs):
-            out = original(*args, **kwargs)
-            mask = out["sample_mask"]
-            keep = (np.arange(mask.shape[-1])
-                    < -(-mask.sum(axis=-1, keepdims=True) // 2))
-            mask &= keep
-            return out
-        return stack_lazy_plan
-
-    return _patched(providers, "stack_lazy_plan", replacement)
+    return patched(trainer, "sgd_update", replacement)
 
 
 def no_exchange():
@@ -63,14 +45,19 @@ def no_exchange():
             return x
         return replica_all_sum
 
-    return _patched(tree, "replica_all_sum", replacement)
+    return patched(tree, "replica_all_sum", replacement)
 
 
 FAULTS = {
     "unchanged_state": unchanged_state,
-    "half_batch": half_batch,
     "no_exchange": no_exchange,
 }
+
+
+def plant(name: str, family):
+    """The fault ``name``, planted: the family's own where its module
+    defines one, else the program-wide one of ``FAULTS``."""
+    return (getattr(family, name, None) or FAULTS[name])()
 
 
 def applicable(traffic: dict) -> list:

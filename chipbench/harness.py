@@ -1,11 +1,37 @@
 """One run of one cell: set-up, the measured window, the optional trace, and
 the comparison with the reference that decides ``correct``.
 
-Everything particular to a configuration, a traffic mix or a per-layer
-metric is read from files found by name: ``configs/<config>.json``,
-``traffic/<traffic>.json``, ``metrics/<metric>.py`` and
-``limits/<workload>.json``, with the cell itself an entry of the
-repository's ``BENCHMARK.json``.
+Everything particular to a configuration, a traffic mix, a model family or
+a per-layer metric is read from files found by name:
+``configs/<config>.json``, ``traffic/<traffic>.json``,
+``families/<family>.py`` (the configuration's ``"family"``),
+``metrics/<metric>.py`` and ``limits/<workload>.json``, with the cell
+itself an entry of the repository's ``BENCHMARK.json``.
+
+A family module holds what is particular to one model; the harness calls
+nothing else of it:
+
+* ``make_data(config, traffic, seeds)``: the data set from the seeds of
+  ``child_seeds``, an object with a ``stats`` dict (printed) that the
+  family's other functions take;
+* ``make_provider(data, config, traffic, seed)``: ``(provider,
+  test_batches)``, the program's provider over the data and the batches
+  the window evaluates. The provider records in ``grids`` what it packs
+  for each mega-batch: per round, per replica, None or the entry the
+  family's ``pack`` takes, whose ``len`` is its number of samples;
+* ``make_model(config)`` and ``TRAINER_KWARGS``: the program's model and
+  the ``ElasticTrainer`` arguments particular to it;
+* ``n_params(config)``: parameters of one replica, for the merge's work;
+* ``train_work(config, data, grids, device_kind)``: ``model_flops``,
+  ``trained_samples`` and each kernel's ``<kernel>_least_s`` over the
+  trained grids; ``eval_work(config, data, test_batches, device_kind)``:
+  each kernel's ``<kernel>_least_s`` of one evaluation on one chip;
+* the model's half of the plain reference, which imports nothing of the
+  program: ``init_params(seed, config, dtype)``, ``loss(params, batch)``
+  and ``pack(data, entries_per_replica, b_max)`` (``reference.py``);
+* ``half_batch()``: the fault of ``faults.applicable`` that breaks where
+  the family packs its batches, a context manager like those of
+  ``faults.py`` (``faults.plant`` takes a family's own fault first).
 
 The window drives the program's ``ElasticTrainer.run_megabatch`` in the
 order of ``ElasticTrainer.run``: dispatch mega-batch N with the next one
@@ -15,7 +41,7 @@ every program the window runs, and the reference follows them.
 """
 from __future__ import annotations
 
-import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -28,7 +54,7 @@ import time
 
 import numpy as np
 
-from chipbench import compare, synth, work
+from chipbench import compare, work
 from chipbench import trace as tr
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -55,6 +81,7 @@ def load_cell(root: str, workload: str) -> dict:
         config = json.load(f)
     with open(os.path.join(here, "traffic", f"{cell['traffic']}.json")) as f:
         traffic = json.load(f)
+    family = load_named(here, "families", config["family"])
 
     def applies(m):
         return "workloads" not in m or workload in m["workloads"]
@@ -65,18 +92,28 @@ def load_cell(root: str, workload: str) -> dict:
         "chips": int(cell["chips"]),
         "config": config,
         "traffic": traffic,
+        "family": family,
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
         "limits": compare.load_limits(here, workload),
     }
 
 
-def metric_reader(here: str, name: str):
-    path = os.path.join(here, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
+@functools.lru_cache(maxsize=None)
+def load_named(here: str, kind: str, name: str):
+    """The module ``<here>/<kind>/<name>.py``, loaded once a process (the
+    reference's jitted round is keyed by the family's ``loss``)."""
+    path = os.path.join(here, kind, f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"chipbench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
+    # dataclasses look their module up by name while the module runs
+    sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(here: str, name: str):
+    return load_named(here, "metrics", name).read
 
 
 def child_seeds(seed: int) -> dict:
@@ -86,71 +123,20 @@ def child_seeds(seed: int) -> dict:
 
 
 # ----------------------------------------------------------------------------
-# data and trainer
+# the trainer
 # ----------------------------------------------------------------------------
 
 
-@dataclasses.dataclass
-class Data:
-    train: dict
-    test: dict
-    k: int
-    n_lab: int
-    stats: dict
-
-
-def make_data(config: dict, traffic: dict, seeds: dict) -> Data:
-    csr = synth.make_xml_csr(
-        traffic["samples"], config["n_features"], config["n_classes"],
-        config["avg_nnz"], config["avg_labels"], config["nnz_sigma"],
-        seeds["data"],
-    )
-    train, test = synth.split(csr, traffic["test_frac"], seeds["split"])
-    k, n_lab = synth.slot_widths(train)
-    return Data(train, test, k, n_lab, synth.stats(csr, k))
-
-
-def make_recording_provider(data: Data, config: dict, seed: int):
-    """The program's SparseProvider over the benchmark's data, recording the
-    sample ids of every plan grid it packs (grid ``m`` feeds mega-batch
-    ``m``) and the samples it hands over."""
-    from repro.data.batcher import SparseBatcher
-    from repro.data.providers import SparseProvider
-    from repro.data.sparse import SparseDataset
-
-    @dataclasses.dataclass
-    class RecordingProvider(SparseProvider):
-        grids: list = dataclasses.field(default_factory=list)
-
-        def stack_plan(self, grid, b_slots, out=None):
-            self.grids.append([[None if p is None else np.array(p.ids)
-                                for p in row] for row in grid])
-            return super().stack_plan(grid, b_slots, out=out)
-
-    def dataset(c):
-        return SparseDataset(
-            n_features=config["n_features"], n_classes=config["n_classes"],
-            indptr=c["indptr"], indices=c["indices"], values=c["values"],
-            label_ptr=c["label_ptr"], labels=c["labels"],
-        )
-
-    batcher = SparseBatcher(dataset(data.train), max_nnz=data.k,
-                            max_labels=data.n_lab, seed=seed)
-    return RecordingProvider(batcher), dataset(data.test)
-
-
-def build_trainer(config: dict, traffic: dict, provider, devices: list, seed: int):
+def build_trainer(family, config: dict, traffic: dict, provider, devices: list,
+                  seed: int):
     """The trainer as ``repro.launch.train.main`` builds it, with the
     traffic's algorithm parameters stated explicitly."""
     from repro.configs.base import ElasticConfig
     from repro.core import algorithms
     from repro.core.heterogeneity import SpeedModel
     from repro.core.trainer import ElasticTrainer
-    from repro.models.xml_mlp import XMLMLPConfig, make_model
     from repro.optim.sgd import SGDConfig
 
-    if config["dtype"] != "float32":
-        raise ValueError(f"unsupported dtype {config['dtype']!r}")
     n_rep = algorithms.get(traffic["algorithm"]).resolve_n_replicas(traffic["replicas"])
     cfg = ElasticConfig(
         algorithm=traffic["algorithm"], placement=traffic["placement"],
@@ -164,15 +150,11 @@ def build_trainer(config: dict, traffic: dict, provider, devices: list, seed: in
         from repro.launch.mesh import make_replica_mesh
 
         mesh = make_replica_mesh(n_rep, devices=devices)
-    model = make_model(XMLMLPConfig(
-        n_features=config["n_features"], n_classes=config["n_classes"],
-        hidden=config["hidden"],
-    ))
     speed = SpeedModel(n_rep, max_gap=traffic["speed_max_gap"], seed=seed)
     return ElasticTrainer(
-        model=model, provider=provider, cfg=cfg, sgd=SGDConfig(),
-        base_lr=traffic["lr"], speed=speed, seed=seed, engine="scan",
-        sparse_grads=True, mesh=mesh, overlap=True,
+        model=family.make_model(config), provider=provider, cfg=cfg,
+        sgd=SGDConfig(), base_lr=traffic["lr"], speed=speed, seed=seed,
+        engine="scan", mesh=mesh, overlap=True, **family.TRAINER_KWARGS,
     )
 
 
@@ -293,18 +275,18 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices: list,
     record."""
     import jax
 
-    config, traffic = cell["config"], cell["traffic"]
+    config, traffic, family = cell["config"], cell["traffic"], cell["family"]
     seeds = child_seeds(seed)
     rec = {"seeds": seeds}
     with CompileCounter() as compiles:
         t_import = time.perf_counter()
-        data = make_data(config, traffic, seeds)
+        data = family.make_data(config, traffic, seeds)
         rec["data"] = data.stats
-        provider, test_ds = make_recording_provider(data, config, seeds["stream"])
-        test_batches = provider.test_batches(test_ds, traffic["b_max"],
-                                             max_samples=traffic["eval_samples"])
+        provider, test_batches = family.make_provider(data, config, traffic,
+                                                      seeds["stream"])
         t_data = time.perf_counter()
-        trainer = build_trainer(config, traffic, provider, devices, seeds["program"])
+        trainer = build_trainer(family, config, traffic, provider, devices,
+                                seeds["program"])
         delta_norms = delta_norms_fn(trainer)
         loop = Loop(trainer, trainer.init_state(), test_batches)
         prog = {"losses": [], "deltas": {}}
@@ -385,9 +367,8 @@ def check(cell, data, grids, program_seed, prog, controls=()) -> dict:
     from chipbench import reference
 
     def ref_run(dtype):
-        return reference.run(cell["config"], cell["traffic"], data.train, data.k,
-                             data.n_lab, grids, program_seed, checked=CHECKED,
-                             dtype=dtype)
+        return reference.run(cell["family"], cell["config"], cell["traffic"], data,
+                             grids, program_seed, checked=CHECKED, dtype=dtype)
 
     t = time.perf_counter()
     ref = ref_run(jnp.float32)
@@ -427,37 +408,17 @@ def read_trace(trace_dir, cell, data, provider, test_batches, first, last,
 def window_work(cell, data, grids, test_batches, n_evals, devices) -> dict:
     """Work of the window's calls, counted from the batches it trained and
     evaluated on."""
-    config, traffic = cell["config"], cell["traffic"]
+    config, traffic, family = cell["config"], cell["traffic"], cell["family"]
     kind = devices[0].device_kind
-    hidden, n_classes = config["hidden"], config["n_classes"]
     chips = len(devices)
-    spmm_least, model_flops, trained = 0.0, 0.0, 0
-    csr = data.train
-    for grid in grids:
-        for row in grid:
-            for ids in row:
-                if ids is None:
-                    continue
-                b = _padded(csr, ids, data.k)
-                f, by = work.spmm_work(b["feat_idx"], b["feat_mask"],
-                                       b["sample_mask"], hidden)
-                spmm_least += work.least_seconds(f, by, kind)
-                nnz = b["feat_mask"].sum(axis=1)[b["sample_mask"]]
-                model_flops += float(work.model_flops_per_sample(
-                    nnz, hidden, n_classes).sum())
-                trained += len(ids)
+    out = family.train_work(config, data, grids, kind)
     # every chip evaluates every test batch under the sharded placement;
     # under vmap one chip does
     eval_copies = chips if traffic["placement"] == "sharded" else 1
-    eval_least = 0.0
-    for batch in test_batches:
-        f, by = work.spmm_work(batch.feat_idx, batch.feat_mask,
-                               batch.sample_mask, hidden)
-        eval_least += work.least_seconds(f, by, kind)
-    spmm_least += eval_least * n_evals * eval_copies
+    for key, least in family.eval_work(config, data, test_batches, kind).items():
+        out[key] += least * n_evals * eval_copies
 
-    n_params = (config["n_features"] * hidden + hidden
-                + hidden * n_classes + n_classes)
+    n_params = family.n_params(config)
     merge_least = 0.0
     if traffic["algorithm"] == "adaptive":
         n_rep = traffic["replicas"]
@@ -468,21 +429,9 @@ def window_work(cell, data, grids, test_batches, n_evals, devices) -> dict:
             per = work.weighted_merge_bytes(n_params, n_rep, True)
             flops = work.weighted_merge_flops(n_params, n_rep, True)
         merge_least = work.least_seconds(flops, per, kind) * len(grids)
-    return {
-        "spmm_least_s": spmm_least,
-        "weighted_merge_least_s": merge_least,
-        "model_flops": model_flops,
-        "trained_samples": trained,
-        "peak_flops": work.peaks(kind)["flops"],
-        "chips": chips,
-    }
-
-
-def _padded(csr, ids, k):
-    from chipbench.reference import pack
-
-    b = pack(csr, [ids], len(ids), k, 1)
-    return {key: v[0] for key, v in b.items()}
+    out.update(weighted_merge_least_s=merge_least,
+               peak_flops=work.peaks(kind)["flops"], chips=chips)
+    return out
 
 
 # ----------------------------------------------------------------------------

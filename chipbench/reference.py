@@ -1,7 +1,8 @@
-"""Plain reference of the trained job: the sparse MLP's loss and gradients in
-straightforward ``jax.numpy`` at ``HIGHEST`` matmul precision, dense SGD,
-Algorithm 1 (batch size scaling) and Algorithm 2 (normalized merge with
-perturbation and global-model momentum) of arXiv:2110.07029.
+"""Plain reference of the trained job: the model family's loss and
+gradients (``families/<family>.py``: ``init_params``, ``loss``, ``pack``),
+dense SGD, Algorithm 1 (batch size scaling) and Algorithm 2 (normalized
+merge with perturbation and global-model momentum) of arXiv:2110.07029, in
+straightforward ``jax.numpy``.
 
 It imports nothing of the program and takes nothing the program made: the
 weights come from the configuration's stated initialization and the seed,
@@ -18,50 +19,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-def init_params(seed: int, n_features: int, n_classes: int, hidden: int,
-                dtype=jnp.float32) -> dict:
-    """w1 ~ N(0, 1/n_features) and w2 ~ N(0, 1/hidden) from the two halves
-    of ``split(PRNGKey(seed))``; biases zero (the configuration's ``init``)."""
-    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
-    w1 = jax.random.normal(k1, (n_features, hidden), jnp.float32)
-    w2 = jax.random.normal(k2, (hidden, n_classes), jnp.float32)
-    return {
-        "w1": (w1 * (1.0 / jnp.sqrt(n_features))).astype(dtype),
-        "b1": jnp.zeros((hidden,), dtype),
-        "w2": (w2 * (1.0 / jnp.sqrt(hidden))).astype(dtype),
-        "b2": jnp.zeros((n_classes,), dtype),
-    }
+
+def _dtype(tree):
+    return jax.tree_util.tree_leaves(tree)[0].dtype
 
 
-def loss(params: dict, batch: dict):
-    """Mean over valid samples of the mean over each sample's labels of
-    -log softmax; the input layer is a gather of W1 rows weighted by the
-    slot values."""
-    dtype = params["w1"].dtype
-    prec = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
-    scale = (batch["feat_val"] * batch["feat_mask"]).astype(dtype)
-    rows = params["w1"][batch["feat_idx"]]
-    h = jax.nn.relu(
-        jnp.einsum("bk,bkh->bh", scale, rows, precision=prec) + params["b1"]
-    )
-    logits = jnp.dot(h, params["w2"], precision=prec) + params["b2"]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    lab = jnp.take_along_axis(logp, batch["label_idx"], axis=-1)
-    lmask = batch["label_mask"].astype(dtype)
-    per_sample = -jnp.sum(lab * lmask, axis=-1) / jnp.maximum(
-        jnp.sum(lmask, axis=-1), 1
-    )
-    smask = batch["sample_mask"].astype(dtype)
-    return jnp.sum(per_sample * smask) / jnp.maximum(jnp.sum(smask), 1)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _round(replicas, batch, lr, live):
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("loss",))
+def _round(replicas, batch, lr, live, loss):
     """One lockstep round over the replicas: plain SGD on each live one."""
 
     def one(p, b, lr_i, live_i):
         value, g = jax.value_and_grad(loss)(p, b)
-        step = (lr_i * live_i).astype(p["w1"].dtype)
+        step = (lr_i * live_i).astype(_dtype(p))
         return jax.tree_util.tree_map(lambda x, gx: x - step * gx, p, g), value
 
     return jax.vmap(one)(replicas, batch, lr, live)
@@ -77,7 +46,7 @@ def _replica_norms(replicas):
 
 @jax.jit
 def _merge(replicas, alphas, g, gp, gamma):
-    dtype = replicas["w1"].dtype
+    dtype = _dtype(replicas)
     return jax.tree_util.tree_map(
         lambda r, a, b: (
             jnp.tensordot(alphas.astype(dtype), r, axes=1)
@@ -125,36 +94,10 @@ def merge_weights(u, b, norms_per_param, pert_thr, delta):
     return alphas
 
 
-def pack(csr: dict, ids_per_replica: list, b_max: int, k: int, n_lab: int) -> dict:
-    """(R, b_max, ...) padded batches; a replica with ``None`` gets an empty
-    batch. A sample keeps its first ``k`` features and ``n_lab`` labels."""
-    r = len(ids_per_replica)
-    out = {
-        "feat_idx": np.zeros((r, b_max, k), np.int32),
-        "feat_val": np.zeros((r, b_max, k), np.float32),
-        "feat_mask": np.zeros((r, b_max, k), bool),
-        "label_idx": np.zeros((r, b_max, n_lab), np.int32),
-        "label_mask": np.zeros((r, b_max, n_lab), bool),
-        "sample_mask": np.zeros((r, b_max), bool),
-    }
-    for i, ids in enumerate(ids_per_replica):
-        for row, sid in enumerate(() if ids is None else ids):
-            s, e = csr["indptr"][sid], csr["indptr"][sid + 1]
-            n = min(e - s, k)
-            out["feat_idx"][i, row, :n] = csr["indices"][s:s + n]
-            out["feat_val"][i, row, :n] = csr["values"][s:s + n]
-            out["feat_mask"][i, row, :n] = True
-            s, e = csr["label_ptr"][sid], csr["label_ptr"][sid + 1]
-            n = min(e - s, n_lab)
-            out["label_idx"][i, row, :n] = csr["labels"][s:s + n]
-            out["label_mask"][i, row, :n] = True
-            out["sample_mask"][i, row] = True
-    return out
-
-
-def run(config: dict, traffic: dict, csr: dict, k: int, n_lab: int,
-        grids: list, seed: int, checked: tuple = (1, 3), dtype=jnp.float32) -> dict:
-    """Train the plan's first ``len(grids)`` mega-batches.
+def run(family, config: dict, traffic: dict, data, grids: list, seed: int,
+        checked: tuple = (1, 3), dtype=jnp.float32) -> dict:
+    """Train the plan's first ``len(grids)`` mega-batches of the model of
+    ``family`` (its module) on ``data`` (what its ``make_data`` made).
 
     ``grids[m][r][i]`` holds the sample ids replica ``i`` trained on in
     round ``r`` of mega-batch ``m`` (None: no batch). Returns each
@@ -166,8 +109,7 @@ def run(config: dict, traffic: dict, csr: dict, k: int, n_lab: int,
     alg = traffic["algorithm"]
     n_rep = 1 if alg == "single" else int(traffic["replicas"])
     b_max = int(traffic["b_max"])
-    params0 = init_params(seed, config["n_features"], config["n_classes"],
-                          config["hidden"], dtype)
+    params0 = family.init_params(seed, config, dtype)
     replicas = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x, (n_rep,) + x.shape), params0)
     g = gp = params0
@@ -182,9 +124,9 @@ def run(config: dict, traffic: dict, csr: dict, k: int, n_lab: int,
             live = np.array([p is not None for p in row], np.float32)
             if not live.any():
                 continue
-            batch = pack(csr, row, b_max, k, n_lab)
+            batch = family.pack(data, row, b_max)
             replicas, values = _round(replicas, batch, jnp.asarray(lr, jnp.float32),
-                                      jnp.asarray(live))
+                                      jnp.asarray(live), loss=family.loss)
             values = np.asarray(values, np.float64)
             round_losses.append(float((values * live).sum() / live.sum()))
         losses.append(float(np.mean(round_losses)))

@@ -18,7 +18,7 @@ import pytest  # noqa: E402
 TINY = dict(n_features=4096, n_classes=1024, hidden=32, avg_nnz=16, avg_labels=3)
 # the repository's Amazon cell whose limits each traffic's tiny cell takes
 LIMITS_OF = {"adaptive.r4": "amazon670k.adaptive.r4",
-             "adaptive.r4.sharded": "amazon670k.adaptive.r4",
+             "adaptive.r4.sharded": "amazon670k.adaptive.r4.chips4",
              "single.r1": "amazon670k.single.r1"}
 
 
@@ -29,7 +29,7 @@ def make_root(path, cells=("adaptive.r4", "adaptive.r4.sharded", "single.r1")):
     the repository's Amazon cell in ``LIMITS_OF``."""
     src = os.path.join(ROOT, "chipbench")
     dst = os.path.join(path, "chipbench")
-    for d in ("configs", "traffic", "metrics", "limits"):
+    for d in ("configs", "traffic", "families", "metrics", "limits"):
         shutil.copytree(os.path.join(src, d), os.path.join(dst, d))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)

@@ -17,7 +17,7 @@ def run(root, workload, fault=None):
         rec = harness.run_cell(cell, 2**33 + 11, 0.5, False, devices,
                                time.perf_counter())
     else:
-        with faults.FAULTS[fault]():
+        with faults.plant(fault, cell["family"]):
             rec = harness.run_cell(cell, 2**33 + 11, 0.5, False, devices,
                                    time.perf_counter())
     out, lines = harness.result(cell, rec, False, devices)
