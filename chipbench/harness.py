@@ -28,7 +28,12 @@ nothing else of it:
   each kernel's ``<kernel>_least_s`` of one evaluation on one chip;
 * the model's half of the plain reference, which imports nothing of the
   program: ``init_params(seed, config, dtype)``, ``loss(params, batch)``
-  and ``pack(data, entries_per_replica, b_max)`` (``reference.py``);
+  and ``pack(data, entries_per_replica, b_max)`` (``reference.py``).
+  ``init_params`` may return any pytree of arrays, and the program's
+  ``init`` a tree of the same paths: each leaf is read by its path, the
+  keys joined by ``/`` (``w1``; ``blocks/pos0/attn/wq``). Under the
+  ``sharded`` placement the reference holds one replica a chip, so one
+  replica must fit a chip beside its gradient and the two global copies;
 * ``half_batch()``: the fault of ``faults.applicable`` that breaks where
   the family packs its batches, a context manager like those of
   ``faults.py`` (``faults.plant`` takes a family's own fault first).
@@ -240,18 +245,18 @@ class Loop:
 
 def delta_norms_fn(trainer):
     """Per-leaf norm of (global model - the program's initial model), on the
-    device; the initial model is made again from the trainer's seed."""
+    device, each leaf named by its tree path as the reference names it; the
+    initial model is made again from the trainer's seed."""
     import jax
-    import jax.numpy as jnp
+
+    from chipbench import reference
 
     # the key is an argument, not a constant: one program serves every seed
     key = jax.random.PRNGKey(trainer.seed)
 
     @jax.jit
     def fn(g, key):
-        g0 = trainer.model.init(key)
-        return {k: jnp.sqrt(jnp.sum(jnp.square(
-            g[k].astype(jnp.float32) - g0[k].astype(jnp.float32)))) for k in g}
+        return reference.delta_norms(g, trainer.model.init(key))
 
     return lambda g: {k: float(v) for k, v in fn(g, key).items()}
 
@@ -357,18 +362,20 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices: list,
     del loop, trainer, provider, test_batches
     gc.unfreeze()
     gc.collect()
-    rec["check"] = check(cell, data, grids, seeds["program"], prog, controls)
+    rec["check"] = check(cell, data, grids, seeds["program"], prog, devices,
+                         controls)
     return rec
 
 
-def check(cell, data, grids, program_seed, prog, controls=()) -> dict:
+def check(cell, data, grids, program_seed, prog, devices, controls=()) -> dict:
     import jax.numpy as jnp
 
     from chipbench import reference
 
     def ref_run(dtype):
         return reference.run(cell["family"], cell["config"], cell["traffic"], data,
-                             grids, program_seed, checked=CHECKED, dtype=dtype)
+                             grids, program_seed, checked=CHECKED, dtype=dtype,
+                             devices=devices)
 
     t = time.perf_counter()
     ref = ref_run(jnp.float32)
