@@ -74,6 +74,7 @@ from repro.core.heterogeneity import (
 )
 from repro.core.scheduler import DynamicScheduler
 from repro.data.batcher import StagingBuffers
+from repro.kernels.weighted_merge.ops import flat_leaves
 from repro.models.protocol import TrainableModel, as_trainable_model
 from repro.optim.sgd import SGDConfig, init_momentum, sgd_update
 from repro.sharding.rules import REPLICA_AXIS, ReplicaMeshPool, replica_spec
@@ -243,15 +244,15 @@ class ElasticTrainer:
             and isinstance(self.speed, MeasuredSpeedModel)
             else None
         )
-        # rows w1 is stored with beyond the model's features (DESIGN.md
-        # §3), from the parameters' shapes alone: no device work
+        # the parameters' shapes, for the counters (no device work); rows
+        # w1 is stored with beyond the model's features (DESIGN.md §3)
+        self._param_shapes = jax.eval_shape(
+            lambda: self.model.init(jax.random.PRNGKey(0))
+        )
         n_features = getattr(self.model.config, "n_features", None)
         self._w1_pad_rows = None
         if n_features is not None:
-            shapes = jax.eval_shape(
-                lambda: self.model.init(jax.random.PRNGKey(0))
-            )
-            self._w1_pad_rows = shapes["w1"].shape[0] - n_features
+            self._w1_pad_rows = self._param_shapes["w1"].shape[0] - n_features
         self._build_jits()
 
     # ------------------------------------------------------------------
@@ -1343,7 +1344,10 @@ class ElasticTrainer:
         for a provider with nnz slots (``max_nnz``) ``nnz_slots`` (samples ×
         max_nnz) against ``nnz``, the dispatches' work units (Σ min(nnz_i,
         max_nnz)). For a model with ``n_features``, ``w1_pad_rows``: the
-        zero rows w1 is stored with beyond them (a constant)."""
+        zero rows w1 is stored with beyond them (a constant).
+        ``merge_flat_leaves``: the parameter leaves the merge kernel cannot
+        read as a device stores them, and flattens first (the replica axis
+        on the lanes; kernels/weighted_merge), for a device's replicas."""
         samples = sum(d.n_samples for d in plan.dispatches)
         out = {
             "sample_slots": n_rounds * self.cfg.n_replicas * self.cfg.b_max,
@@ -1355,6 +1359,13 @@ class ElasticTrainer:
             out["nnz"] = sum(d.work for d in plan.dispatches)
         if self._w1_pad_rows is not None:
             out["w1_pad_rows"] = self._w1_pad_rows
+        r = self._mesh_width()
+        if self.cfg.placement == "sharded":
+            r //= self.mesh.shape[REPLICA_AXIS]
+        out["merge_flat_leaves"] = flat_leaves(jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct((r,) + l.shape, l.dtype),
+            self._param_shapes,
+        ))
         return out
 
     # ------------------------------------------------------------------

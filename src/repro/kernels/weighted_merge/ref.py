@@ -2,8 +2,9 @@
 
 out = sum_r alphas[r] * replicas[r]  (+ gamma * (g - gp) when provided)
 
-Shapes: replicas (R, N) — the framework flattens each param leaf to 1-D and
-concatenates; the kernel operates on flat chunks.
+Shapes: replicas (R, N), one param leaf flattened per replica; the kernel
+merges each leaf in its own stored shape, and tests compare it with this
+oracle on the flattened view.
 """
 from __future__ import annotations
 

@@ -71,6 +71,135 @@ def test_weighted_merge_pytree():
     assert out["b"]["c"].shape == (100,)
 
 
+# Leaves as a TPU v5e stores them: the major-to-minor order of the device's
+# default layout for each replica-stacked shape (float32, bfloat16).
+MERGE_LEAVES = {
+    (4, 128, 1000): ((0, 2, 1), (0, 2, 1)),   # cols not a multiple of 128
+    (4, 13, 300): ((1, 0, 2), (1, 0, 2)),     # rows not a multiple of 8
+    (1, 128, 2500): ((2, 0, 1), (0, 2, 1)),   # R = 1, as on four chips
+    (4, 1001): ((0, 1), (0, 1)),              # a bias
+    (2, 3, 16, 200): ((0, 1, 2, 3), (0, 1, 2, 3)),  # an extra leading dim
+}
+MERGE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+
+
+def _stored_as(layout, dtype):
+    """Dimension orders of the test's leaves as the CPU (row-major) or a
+    v5e stores them, by shape; a merged leaf as its replicas leave it."""
+    from repro.kernels.weighted_merge.weighted_merge import merged_order
+
+    orders = {}
+    for shape, by_dtype in MERGE_LEAVES.items():
+        order = (tuple(range(len(shape))) if layout == "row_major"
+                 else by_dtype[dtype == jnp.bfloat16])
+        orders[shape] = order
+        orders[shape[1:]] = merged_order(order)
+    return orders
+
+
+def _use_orders(monkeypatch, orders):
+    from repro.kernels.weighted_merge import ops
+
+    monkeypatch.setattr(ops, "stored_order",
+                        lambda shape, _dtype: orders[tuple(shape)])
+
+
+def _merge_case(shape, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    reps = jnp.asarray(rng.normal(size=shape), dtype)
+    alphas = jnp.asarray(rng.random(shape[0]), jnp.float32)
+    g = jnp.asarray(rng.normal(size=shape[1:]), dtype)
+    gp = jnp.asarray(rng.normal(size=shape[1:]), dtype)
+    return reps, alphas, g, gp
+
+
+@pytest.mark.parametrize("layout", ["row_major", "v5e"])
+@pytest.mark.parametrize("momentum", [False, True], ids=["plain", "momentum"])
+@pytest.mark.parametrize("dtype", list(MERGE_DTYPES), ids=str)
+@pytest.mark.parametrize("shape", list(MERGE_LEAVES), ids=str)
+def test_weighted_merge_stored_layout(monkeypatch, shape, dtype, momentum,
+                                      layout):
+    """Each leaf merged in the layout it is stored in equals the oracle on
+    the flattened view, in the leaf's own shape."""
+    dtype = MERGE_DTYPES[dtype]
+    _use_orders(monkeypatch, _stored_as(layout, dtype))
+    reps, alphas, g, gp = _merge_case(shape, dtype)
+    gamma = 0.9 if momentum else 0.0
+    got = merge_pytree({"w": reps}, alphas, {"w": g}, {"w": gp}, gamma)["w"]
+    want = weighted_merge_ref(
+        reps.reshape(shape[0], -1), alphas, g.reshape(-1), gp.reshape(-1),
+        gamma,
+    ).reshape(shape[1:])
+    assert got.shape == shape[1:] and got.dtype == dtype
+    tol = _tol(dtype) if dtype == jnp.bfloat16 else dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(_f32(got), _f32(want), **tol)
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("layout", ["row_major", "v5e"])
+@pytest.mark.parametrize("shape", list(MERGE_LEAVES), ids=str)
+def test_weighted_merge_makes_no_copies(monkeypatch, shape, layout):
+    """The merge's program holds no pad, and every reshape keeps its
+    operand's last two dimensions; a transpose only views a leaf in the
+    order it is stored in (a bitcast on the chip)."""
+    stored = _stored_as(layout, jnp.float32)
+    _use_orders(monkeypatch, stored)
+    reps, alphas, g, gp = _merge_case(shape, jnp.float32)
+    for gamma in (0.0, 0.9):
+        jaxpr = jax.make_jaxpr(
+            lambda x, a, g, gp: merge_pytree({"w": x}, a, {"w": g}, {"w": gp},
+                                             gamma)
+        )(reps, alphas, g, gp)
+        prims = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+        assert "pallas_call" in prims
+        assert "pad" not in prims
+        for e in _eqns(jaxpr.jaxpr):
+            src, dst = e.invars[0].aval.shape, e.outvars[0].aval.shape
+            if e.primitive.name == "reshape":
+                assert src[-2:] == dst[-2:], (src, dst)
+            if e.primitive.name == "transpose":
+                perm = tuple(e.params["permutation"])
+                to_stored = stored.get(tuple(src)) == perm
+                to_logical = (tuple(dst) in stored
+                              and tuple(np.argsort(stored[dst])) == perm)
+                assert to_stored or to_logical, (src, perm)
+
+
+@pytest.mark.parametrize("momentum", [False, True], ids=["plain", "momentum"])
+def test_weighted_merge_flattens_replicas_on_lanes(monkeypatch, momentum):
+    """A leaf stored with its replica axis on the lanes (a v5e keeps (4, 1)
+    as (1, 4)), per-replica scalars and a bfloat16 vector a replica are
+    flattened first and counted; a float32 vector a replica is not."""
+    from repro.kernels.weighted_merge import ops
+
+    orders = {(4, 1): (1, 0), (1,): (0,), (4,): (0,), (): (),
+              (4, 1001): (0, 1), (1001,): (0,)}
+    _use_orders(monkeypatch, orders)
+    tree = {"col": _merge_case((4, 1), jnp.float32)[0],
+            "scalar": _merge_case((4,), jnp.float32, seed=1)[0],
+            "bias16": _merge_case((4, 1001), jnp.bfloat16, seed=2)[0],
+            "bias32": _merge_case((4, 1001), jnp.float32, seed=3)[0]}
+    alphas = jnp.asarray([0.1, 0.2, 0.3, 0.4], jnp.float32)
+    g = {k: jnp.ones(x.shape[1:], x.dtype) for k, x in tree.items()}
+    gp = {k: jnp.zeros(x.shape[1:], x.dtype) for k, x in tree.items()}
+    gamma = 0.9 if momentum else 0.0
+    out = merge_pytree(tree, alphas, g, gp, gamma)
+    for k, x in tree.items():
+        want = weighted_merge_ref(x.reshape(4, -1), alphas,
+                                  g[k].reshape(-1), gp[k].reshape(-1), gamma)
+        assert out[k].shape == x.shape[1:] and out[k].dtype == x.dtype
+        tol = _tol(x.dtype) if x.dtype == jnp.bfloat16 else dict(rtol=1e-5,
+                                                                  atol=1e-6)
+        np.testing.assert_allclose(_f32(out[k]).reshape(-1), _f32(want), **tol)
+    assert ops.flat_leaves(tree) == 3
+
+
 @settings(max_examples=20, deadline=None)
 @given(
     r=st.integers(2, 8),

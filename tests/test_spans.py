@@ -9,7 +9,7 @@ counters of each mega-batch's record.
   layer, the head, the sparse and dense updates and the merge;
 * counters — ``sample_slots`` / ``samples`` / ``nnz_slots`` / ``nnz`` in
   ``info`` agree with the plan, on both mega-batch paths; ``w1_pad_rows``
-  with w1's stored shape;
+  with w1's stored shape; ``merge_flat_leaves`` is 0 for the XML model;
 * cost — the arithmetic does not change: a traced run is bit-identical to
   an untraced one.
 """
@@ -144,6 +144,7 @@ def test_slot_counters_follow_the_plan(split, overlap):
         assert info["nnz_slots"] == info["samples"] * k
         assert 0 < info["nnz"] <= info["nnz_slots"]
         assert info["w1_pad_rows"] == 0  # 512 features: a whole tile
+        assert info["merge_flat_leaves"] == 0  # every leaf read as stored
 
 
 def test_slot_counters_match_across_paths(split):
@@ -153,7 +154,8 @@ def test_slot_counters_match_across_paths(split):
         tr = build_case_trainer("adaptive", "scan", True, train)
         tr.overlap = overlap
         _, mlog = tr.run(3)
-        keys = ("sample_slots", "samples", "nnz_slots", "nnz", "w1_pad_rows")
+        keys = ("sample_slots", "samples", "nnz_slots", "nnz", "w1_pad_rows",
+                "merge_flat_leaves")
         return [{k: r[k] for k in keys} for r in mlog.records]
 
     assert records(True) == records(False)

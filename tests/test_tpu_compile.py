@@ -242,3 +242,67 @@ def test_weighted_merge_momentum_compiles_for_v5e(shape, n):
         shape((n,), jnp.float32),
         shape((n,), jnp.float32),
     )
+
+
+@pytest.fixture
+def merge_ops(topo, monkeypatch):
+    """``kernels/weighted_merge/ops`` lowering natively, with each leaf's
+    layout read from the described chip, as on a v5e."""
+    import numpy as np
+    from jax.experimental.layout import Layout
+
+    from repro.kernels.weighted_merge import ops
+
+    dev = topo.devices[0]
+    monkeypatch.setattr(ops, "_interpret_mode", lambda: False)
+    monkeypatch.setattr(ops, "stored_order", lambda s, dtype: tuple(
+        Layout.from_pjrt_layout(dev.client.get_default_layout(
+            np.dtype(dtype), tuple(s), dev)).major_to_minor))
+    return ops
+
+
+@pytest.mark.parametrize("replicas,gamma", [(R, 0.9), (1, 0.0)],
+                         ids=["one_chip_r4", "four_chips_shard"])
+def test_weighted_merge_reads_leaves_as_stored_for_v5e(
+        merge_ops, shape, replicas, gamma):
+    """The merge of the XML model's four leaves at Amazon-670K widths, R
+    replicas on a chip with the momentum term fused, or one replica (a
+    shard on four chips) without it: nothing w2-sized is made but the
+    kernels' outputs and bitcasts. A v5e stores w2's replicas (R, H, C) as
+    (C, R, H); reading them in another order would copy them."""
+    import numpy as np
+
+    leaves = {"w1": (-(-NF // 8) * 8, H), "b1": (H,), "w2": (H, NC),
+              "b2": (NC,)}
+    reps = {k: shape((replicas,) + s, jnp.float32) for k, s in leaves.items()}
+    glob = {k: shape(s, jnp.float32) for k, s in leaves.items()}
+    fn = lambda x, a, g, gp: merge_ops.merge_pytree(x, a, g, gp, gamma)
+    text = jax.jit(fn).lower(
+        reps, shape((replicas,), jnp.float32), glob, glob
+    ).compile().as_text()
+
+    made = {}  # opcode -> names of the w2-sized arrays it makes
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and np.prod([int(d) for d in m.group(2).split(",") if d]) >= H * NC:
+            made.setdefault(m.group(3), []).append(m.group(1))
+    assert set(made) <= {"parameter", "bitcast", "custom-call"}, made
+    assert [n for n in made["custom-call"]
+            if not n.startswith("weighted_merge")] == [], made
+
+
+@pytest.mark.parametrize("leaf", [(R, H, NC), (R, 22, 2048), (R, 1001),
+                                  (R, 1)], ids=str)
+def test_weighted_merge_bfloat16_compiles_for_v5e(merge_ops, shape, leaf):
+    """bfloat16 leaves: replicas on the sublanes (w2's (C, R, H)), a
+    stacked-layer vector, a bias and a column, which the kernel flattens
+    first; each within the chip's scoped VMEM."""
+    fn = lambda x, a, g, gp: merge_ops.merge(x, a, g, gp, 0.9)
+    _assert_kernel(
+        fn,
+        shape(leaf, jnp.bfloat16),
+        shape((R,), jnp.float32),
+        shape(leaf[1:], jnp.bfloat16),
+        shape(leaf[1:], jnp.bfloat16),
+    )
