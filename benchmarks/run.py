@@ -1,5 +1,4 @@
-"""Benchmark harness: one benchmark per paper table/figure + the roofline
-table from stored dry-run artifacts.
+"""Benchmark harness: one benchmark per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run              # all, fast settings
   PYTHONPATH=src python -m benchmarks.run --only fig6
@@ -12,34 +11,10 @@ import time
 import traceback
 
 
-def _print_roofline():
-    from . import roofline
-
-    rows = roofline.table()
-    if not rows:
-        print("(no dry-run artifacts in results/dryrun — "
-              "run `python -m repro.launch.dryrun` first)")
-        return
-    hdr = ["arch", "shape", "step", "compute_s", "memory_s",
-           "collective_s", "bottleneck", "useful_ratio"]
-    print(",".join(hdr))
-    for r in rows:
-        print(",".join([
-            r["arch"], r["shape"], r["step"],
-            f"{r['t_compute_s']:.3e}", f"{r['t_memory_s']:.3e}",
-            f"{r['t_collective_s']:.3e}", r["bottleneck"],
-            f"{r['useful_ratio']:.3f}",
-        ]))
-    split = {}
-    for r in rows:
-        split[r["bottleneck"]] = split.get(r["bottleneck"], 0) + 1
-    print(f"# {len(rows)} combos; bottleneck split: {split}")
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="all",
-                    help="fig6..fig12 | roofline | all")
+                    help="fig6..fig12 | all")
     ap.add_argument("--full", action="store_true",
                     help="full sweeps (slower); default is fast settings")
     args = ap.parse_args(argv)
@@ -49,9 +24,6 @@ def main(argv=None):
     jobs = {}
     if args.only == "all":
         jobs.update(ALL_FIGURES)
-        jobs["roofline"] = None
-    elif args.only == "roofline":
-        jobs["roofline"] = None
     else:
         jobs[args.only] = ALL_FIGURES[args.only]
 
@@ -60,10 +32,7 @@ def main(argv=None):
         t0 = time.perf_counter()
         print(f"\n{'='*70}\n== {name}\n{'='*70}")
         try:
-            if name == "roofline":
-                _print_roofline()
-            else:
-                fn(fast=not args.full)
+            fn(fast=not args.full)
         except Exception as e:
             failures.append((name, repr(e)))
             traceback.print_exc()

@@ -1,7 +1,6 @@
 """Serving example: batched greedy decoding with a KV/SSM cache for three
 architecture families (dense GQA, attention-free Mamba2, hybrid Jamba) in
-their reduced configurations — the same ``decode_step`` the decode_32k /
-long_500k dry-run shapes lower on the production mesh.
+their reduced configurations, through ``decode_step``.
 
 Run:  PYTHONPATH=src python examples/decode_serve.py
 """

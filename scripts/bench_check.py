@@ -7,10 +7,6 @@ them, so a change could silently halve a speedup and still merge green.
 
 Headline metrics per benchmark (higher is better unless noted):
 
-* ``BENCH_engine.json``      — every entry of ``speedup_steps_per_s``
-  (scan-vs-legacy engine and end-to-end speedups per replica count) and
-  of ``overlap_gain`` (overlapped pipeline vs sequential oracle,
-  DESIGN.md §8)
 * ``BENCH_spmm_grad.json``   — every entry of ``speedup_sparse_over_dense``
 * ``BENCH_algorithms.json``  — per-algorithm ``tta`` (time-to-accuracy,
   LOWER is better; a fresh run that no longer reaches the target where the
@@ -30,7 +26,7 @@ metric trajectories over time, not only the failures.
 Exit code 0 = within tolerance, 1 = regression, 2 = usage/data error.
 
     python scripts/bench_check.py                  # all benchmarks, 25%
-    python scripts/bench_check.py --tolerance 0.1 BENCH_engine.json
+    python scripts/bench_check.py --tolerance 0.1 BENCH_spmm_grad.json
 """
 from __future__ import annotations
 
@@ -41,21 +37,13 @@ import subprocess
 import sys
 
 DEFAULT_TOLERANCE = 0.25
-BENCH_FILES = ("BENCH_engine.json", "BENCH_spmm_grad.json",
-               "BENCH_algorithms.json")
+BENCH_FILES = ("BENCH_spmm_grad.json", "BENCH_algorithms.json")
 
 
 def headline_metrics(name: str, data: dict) -> dict[str, tuple[float | None, bool]]:
     """{metric: (value, higher_is_better)} for one benchmark file."""
     out: dict[str, tuple[float | None, bool]] = {}
-    if name == "BENCH_engine.json":
-        for k, v in data.get("speedup_steps_per_s", {}).items():
-            out[f"speedup_steps_per_s/{k}"] = (float(v), True)
-        # overlap pipeline gain (DESIGN.md §8): scan overlap-on vs
-        # overlap-off end-to-end throughput, per replica count
-        for k, v in data.get("overlap_gain", {}).items():
-            out[f"overlap_gain/{k}"] = (float(v), True)
-    elif name == "BENCH_spmm_grad.json":
+    if name == "BENCH_spmm_grad.json":
         for k, v in data.get("speedup_sparse_over_dense", {}).items():
             out[f"speedup_sparse_over_dense/{k}"] = (float(v), True)
     elif name == "BENCH_algorithms.json":

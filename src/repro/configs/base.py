@@ -5,9 +5,6 @@ Three dataclasses compose the full experiment description:
 * ``ModelConfig``   — architecture (one per assigned arch + the paper's MLP)
 * ``ElasticConfig`` — the paper's Adaptive SGD hyperparameters (Alg. 1 + 2)
 * ``RunConfig``     — batch/seq/step/lr bundle for a run
-
-``INPUT_SHAPES`` holds the four assigned (seq_len, global_batch, mode)
-combinations used by the dry-run and roofline harness.
 """
 from __future__ import annotations
 
@@ -187,27 +184,6 @@ class RunConfig:
     seed: int = 0
     mode: str = "train"  # train | prefill | decode
     warmup_megabatches: int = 0
-
-
-# --------------------------------------------------------------------------
-# Assigned input shapes (dry-run / roofline grid)
-# --------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class InputShape:
-    name: str
-    seq_len: int
-    global_batch: int
-    mode: str  # train | prefill | decode
-
-
-INPUT_SHAPES: dict[str, InputShape] = {
-    "train_4k": InputShape("train_4k", 4096, 256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": InputShape("decode_32k", 32768, 128, "decode"),
-    "long_500k": InputShape("long_500k", 524288, 1, "decode"),
-}
 
 
 def asdict(cfg) -> dict:

@@ -36,13 +36,15 @@ Execution engines (DESIGN.md §1):
     one jitted ``jax.lax.scan`` with replica/momentum buffers donated;
     loss/accuracy/n_valid accumulate on device, so the host syncs once per
     mega-batch instead of once per round.
-  * ``legacy_loop`` — the original per-round host loop (one jitted dispatch
-    + host stack + metric sync per round). Kept as an escape hatch and as
-    the oracle for differential testing (tests/test_megabatch_engine.py).
+  * ``legacy_loop`` — a per-round host loop over the same staged
+    mega-batch (one jitted round dispatch + metric read per round). The
+    test reference for the scan engine (tests/test_megabatch_engine.py).
 
-Both engines trace the *same* ``round_body`` — including the algorithm's
-``RoundTransforms`` (gradient transform + post-round correction) — so the
-strategy hooks behave identically under either executor.
+Both engines run inside the one mega-batch path (``run_megabatch``: stage,
+dispatch, adapt, stage the next, collect, merge) and trace the *same*
+``round_body`` — including the algorithm's ``RoundTransforms`` (gradient
+transform + post-round correction) — so the strategy hooks behave
+identically under either executor.
 
 Elastic membership (DESIGN.md §6): the replica count R may change between
 mega-batches — ``resize`` re-plans (scheduler + speed model at the new R),
@@ -163,8 +165,9 @@ class ElasticTrainer:
                                      # while every replica stays finite
     overlap: bool = True             # overlapped mega-batch pipeline
                                      # (DESIGN.md §8): stage N+1 + dispatch
-                                     # eval while N executes. scan engine
-                                     # only; False = the sequential oracle
+                                     # eval while N executes. False = stage
+                                     # each mega-batch just in time and
+                                     # evaluate synchronously (same path)
     mesh: Optional[Mesh] = None      # replica mesh for cfg.placement='sharded'
                                      # (None = build one over the local devices)
     multihost: Optional[Any] = None  # launch.multihost.MultihostContext: span
@@ -1133,69 +1136,7 @@ class ElasticTrainer:
         )
 
     # ------------------------------------------------------------------
-    # round execution engines
-    # ------------------------------------------------------------------
-    def _run_rounds_scan(self, state, plan, b_slots, transforms):
-        """Device-resident engine: pre-stack the plan, scan all rounds.
-        Host span: the plan grid is built at the global R (every process
-        plans identically), but only this process's replica columns are
-        uploaded and executed."""
-        R = self.cfg.n_replicas
-        mb = int(state.megabatch_idx)
-        with trace_span("stage", megabatch=mb):
-            with trace_span("stage.plan", megabatch=mb):
-                grid = plan.payload_grid(R, min_rounds=self._bucket_rounds(plan))
-            with trace_span("stage.pack", megabatch=mb):
-                batches_np, mask = self.provider.stack_plan(grid, b_slots)
-            lr = np.asarray(state.lr, np.float32)
-            if self._span is not None:
-                sl = self._span_slice()
-                batches_np = {k: v[:, sl] for k, v in batches_np.items()}
-                mask = mask[:, sl]
-                lr = lr[sl]
-            with trace_span("stage.upload", megabatch=mb):
-                batches = {k: jnp.asarray(v) for k, v in batches_np.items()}
-                lr, mask = jnp.asarray(lr), jnp.asarray(mask)
-        with trace_span("dispatch", megabatch=mb):
-            replicas, momentum, m = self._megabatch(
-                state.replicas,
-                state.momentum,
-                batches,
-                lr,
-                mask,
-                transforms=transforms,
-            )
-        # single host sync per mega-batch
-        loss, acc = self._finish_metrics(m)
-        return replicas, momentum, loss, acc
-
-    def _run_rounds_legacy(self, state, plan, b_slots, transforms):
-        """Original per-round host loop (escape hatch / differential oracle)."""
-        R = self.cfg.n_replicas
-        grid = plan.payload_grid(R)
-        replicas, momentum = state.replicas, state.momentum
-        losses, accs = [], []
-        for row in grid:
-            payloads = [p if p is not None else self.provider.empty(b_slots) for p in row]
-            update_mask = jnp.asarray(
-                [1.0 if p is not None else 0.0 for p in row], jnp.float32
-            )
-            batch = {k: jnp.asarray(v) for k, v in self.provider.stack(payloads).items()}
-            lr_vec = jnp.asarray(state.lr, jnp.float32)
-            replicas, momentum, m = self._round(
-                replicas, momentum, batch, lr_vec, update_mask,
-                transforms=transforms,
-            )
-            w = np.asarray(update_mask)
-            if w.sum() > 0:
-                losses.append(float((np.asarray(m["loss"]) * w).sum() / w.sum()))
-                accs.append(float((np.asarray(m["accuracy"]) * w).sum() / w.sum()))
-        loss = float(np.mean(losses)) if losses else float("nan")
-        acc = float(np.mean(accs)) if accs else float("nan")
-        return replicas, momentum, loss, acc
-
-    # ------------------------------------------------------------------
-    # one mega-batch
+    # one mega-batch (DESIGN.md §1, §8)
     # ------------------------------------------------------------------
     def run_megabatch(
         self, state: ElasticState, prefetch: Optional[bool] = None
@@ -1204,16 +1145,24 @@ class ElasticTrainer:
 
         Generic engine sequence — every step delegates to the strategy:
         ``algo.plan`` → rounds (with ``algo.round_transforms`` traced in) →
-        ``algo.merge`` → ``algo.adapt`` → merge-cost accounting.
+        merge-cost accounting and ``algo.adapt`` → ``algo.merge``.
 
-        With ``overlap`` on (and the scan engine), the pipelined variant
-        runs instead (DESIGN.md §8): the mega-batch is dispatched from a
-        pre-staged device-resident plan, and while the device executes, the
-        host adapts b/lr and stages mega-batch N+1 (plan → fused pack into a
-        double buffer → one batched upload). ``prefetch=False`` suppresses
-        staging the *next* mega-batch (used for the final one); the default
-        prefetches. Both variants produce bit-identical trajectories under
-        the simulated speed model.
+        The one mega-batch path (DESIGN.md §8): dispatch N from its staged
+        plan (prefetched at the previous boundary, else staged now), then
+        do the host work for N+1 (adapt → plan → fused pack → batched
+        upload) *before* the single host sync that collects N's metrics —
+        on an async backend the device is busy with N throughout.
+        ``prefetch`` stages N+1 only with ``overlap`` on; it is opt-in
+        (run() and bench loops pass it), so a bare call leaves no dangling
+        staged plan and the caller's live cursors (provider / clock /
+        speed) stay where one sequential mega-batch leaves them.
+
+        Host-stateful operations keep the order … plan N → merge-cost clock
+        bump N → plan N+1 … with or without prefetch, and ``merge``/
+        ``adapt``/the guard are pure functions of (state, plan, device
+        results), so trajectories are bit-identical either way under the
+        simulated speed model. Under a measured speed model, a prefetched
+        plan N+1 is made with factors one window stale (DESIGN.md §8).
 
         Donation contract: with the scan engine on TPU/GPU, ``state.replicas``
         and ``state.momentum`` are DONATED to the device program — treat
@@ -1221,172 +1170,6 @@ class ElasticTrainer:
         (On CPU donation is disabled and old states stay readable.)
         """
         self._current_megabatch = int(state.megabatch_idx)
-        if self.overlap and self.engine == "scan":
-            # prefetch is opt-in (run() and bench loops pass it): a bare
-            # run_megabatch call must leave no dangling staged plan, so the
-            # caller's live cursors (provider / clock / speed) stay exactly
-            # where a sequential mega-batch would leave them
-            return self._run_megabatch_overlap(state, bool(prefetch))
-        # a stale prefetch (e.g. the overlap flag was flipped off between
-        # calls) must not leak advanced cursors into the sequential path
-        if self._staged is not None:
-            self.invalidate_prefetch()
-        return self._run_megabatch_sync(state)
-
-    def _run_megabatch_sync(self, state: ElasticState) -> tuple[ElasticState, dict]:
-        """Sequential mega-batch: plan → execute → merge, one after another.
-
-        The differential oracle for the overlap pipeline (``--overlap off``):
-        this path is the pre-pipeline code, byte for byte."""
-        cfg = self.cfg
-        R = cfg.n_replicas
-        mega_samples = cfg.mega_batch * cfg.b_max
-        b_slots = cfg.b_max
-        mb = int(state.megabatch_idx)
-
-        def fetch(i, take):
-            payload = self.provider.fetch(take, b_slots)
-            return payload, self.provider.work_units(payload)
-
-        with trace_span("stage", megabatch=mb), \
-                trace_span("stage.plan", megabatch=mb):
-            plan = self.algo.plan(self.scheduler, state, mega_samples, fetch)
-
-        # ---- execute lockstep rounds ----
-        run_rounds = (
-            self._run_rounds_legacy if self.engine == "legacy_loop"
-            else self._run_rounds_scan
-        )
-        # measured-speed feedback (DESIGN.md §5): time the real execution of
-        # the mega-batch and feed it back so the *next* plan's virtual clock
-        # runs on observed relative speeds instead of simulated factors. The
-        # engines sync metrics to host before returning, so the window
-        # brackets actual device work.
-        measure = isinstance(self.speed, MeasuredSpeedModel)
-        t_start = self.speed.begin() if measure else None
-        if measure and self._shard_timer is not None:
-            self._shard_timer.reset(int(self.mesh.shape[REPLICA_AXIS]))
-        replicas, momentum, train_loss, train_acc = run_rounds(
-            state, plan, b_slots, self._transforms
-        )
-        if measure:
-            self._observe_window(plan, R, self.speed.elapsed(t_start))
-
-        # ---- non-finite guard (DESIGN.md §7) ----
-        # A replica whose params went NaN/Inf during the rounds is healed
-        # *before* the barrier so it can never poison the merged global.
-        # Detection is read-only: a healthy mega-batch is bit-identical
-        # with the guard on or off.
-        guard_repaired: list[int] = []
-        if self.guard_nonfinite:
-            finite = self._global_finite_rows(replicas)
-            if not finite.all():
-                replicas, momentum = self._repair_nonfinite(
-                    state, replicas, momentum, finite
-                )
-                guard_repaired = np.flatnonzero(~finite).tolist()
-
-        # ---- merge (the barrier) + between-mega-batch adaptation ----
-        with trace_span("merge", megabatch=mb):
-            outcome = self.algo.merge(self, state, plan, replicas)
-        alphas = (
-            outcome.alphas if outcome.alphas is not None else np.full(R, 1.0 / R)
-        )
-        with trace_span("adapt", megabatch=mb):
-            new_b, new_lr = self.algo.adapt(state, plan, cfg)
-            # merge happens at the barrier and costs virtual time on every
-            # replica; the strategy decides how many merges a mega-batch
-            # incurs (per-round for eager synchronous schemes, once for
-            # barrier-only).
-            n_merges = self.algo.merges_per_megabatch(plan)
-            self.scheduler.clock.t[:] += self.merge_cost * n_merges
-            virtual_time = float(self.scheduler.clock.t.max())
-
-        new_state = ElasticState(
-            replicas=outcome.replicas,
-            global_model=outcome.global_model,
-            prev_global=outcome.prev_global,
-            momentum=momentum,
-            b=np.asarray(new_b, np.float64),
-            lr=np.asarray(new_lr, np.float64),
-            megabatch_idx=state.megabatch_idx + 1,
-        )
-        info = {
-            "n_replicas": R,
-            "u": plan.u.tolist(),
-            "b": np.round(np.asarray(new_b), 2).tolist(),
-            "lr": np.round(np.asarray(new_lr), 6).tolist(),
-            "alphas": np.round(np.asarray(alphas, np.float64), 4).tolist(),
-            "pert_active": bool(outcome.pert_active),
-            "train_loss": train_loss,
-            "train_accuracy": train_acc,
-            "virtual_time": virtual_time,
-            "n_rounds": plan.n_rounds,
-            **self._slot_counters(
-                plan,
-                plan.n_rounds if self.engine == "legacy_loop"
-                else self._bucket_rounds(plan),
-            ),
-        }
-        if guard_repaired:
-            info["guard_repaired"] = guard_repaired
-        return new_state, info
-
-    def _bucket_rounds(self, plan) -> int:
-        """Rounds the scan engine runs for ``plan``: its round count, padded
-        to a power of two with fully-masked rounds under ``round_bucket``."""
-        n = _next_pow2(plan.n_rounds) if self.round_bucket else plan.n_rounds
-        return max(n, 1)
-
-    def _slot_counters(self, plan, n_rounds: int) -> dict:
-        """What the device computes against what it holds, counted from the
-        plan: ``sample_slots`` (rounds × R × b_max) against ``samples``, and
-        for a provider with nnz slots (``max_nnz``) ``nnz_slots`` (samples ×
-        max_nnz) against ``nnz``, the dispatches' work units (Σ min(nnz_i,
-        max_nnz)). For a model with ``n_features``, ``w1_pad_rows``: the
-        zero rows w1 is stored with beyond them (a constant).
-        ``merge_flat_leaves``: the parameter leaves the merge kernel cannot
-        read as a device stores them, and flattens first (the replica axis
-        on the lanes; kernels/weighted_merge), for a device's replicas."""
-        samples = sum(d.n_samples for d in plan.dispatches)
-        out = {
-            "sample_slots": n_rounds * self.cfg.n_replicas * self.cfg.b_max,
-            "samples": samples,
-        }
-        max_nnz = getattr(self.provider, "max_nnz", None)
-        if max_nnz is not None:
-            out["nnz_slots"] = samples * max_nnz
-            out["nnz"] = sum(d.work for d in plan.dispatches)
-        if self._w1_pad_rows is not None:
-            out["w1_pad_rows"] = self._w1_pad_rows
-        r = self._mesh_width()
-        if self.cfg.placement == "sharded":
-            r //= self.mesh.shape[REPLICA_AXIS]
-        out["merge_flat_leaves"] = flat_leaves(jax.tree_util.tree_map(
-            lambda l: jax.ShapeDtypeStruct((r,) + l.shape, l.dtype),
-            self._param_shapes,
-        ))
-        return out
-
-    # ------------------------------------------------------------------
-    # overlapped mega-batch pipeline (DESIGN.md §8)
-    # ------------------------------------------------------------------
-    def _run_megabatch_overlap(
-        self, state: ElasticState, prefetch: bool
-    ) -> tuple[ElasticState, dict]:
-        """Pipelined mega-batch: dispatch N from the pre-staged arrays, then
-        do all host work for N+1 (adapt → plan → fused pack → batched
-        upload) *before* the single host sync that collects N's metrics —
-        on an async backend the device is busy with N throughout.
-
-        Host-stateful operations keep exactly the sequential path's relative
-        order (… plan N → merge-cost clock bump N → plan N+1 …), and
-        ``merge``/``adapt``/the guard are pure functions of (state, plan,
-        device results), so trajectories are bit-identical to
-        ``_run_megabatch_sync`` under the simulated speed model. Under a
-        measured speed model, plan N+1 is made with factors one window stale
-        — the price of the pipeline, documented in DESIGN.md §8.
-        """
         cfg = self.cfg
         R = cfg.n_replicas
         mb = int(state.megabatch_idx)
@@ -1395,27 +1178,36 @@ class ElasticTrainer:
             staged = self._stage_megabatch(state.b, state.lr, mb)
         plan = staged.plan
 
+        # measured-speed feedback (DESIGN.md §5): the window runs from
+        # dispatch to the metrics collect, which brackets the device work
         measure = isinstance(self.speed, MeasuredSpeedModel)
         t_start = self.speed.begin() if measure else None
         if measure and self._shard_timer is not None:
             self._shard_timer.reset(int(self.mesh.shape[REPLICA_AXIS]))
         with trace_span("dispatch", megabatch=mb):
-            replicas, momentum, m = self._megabatch(
-                state.replicas,
-                state.momentum,
-                staged.batches,
-                staged.lr_dev,
-                staged.mask,
-                transforms=self._transforms,
-            )
+            if self.engine == "legacy_loop":
+                replicas, momentum, m = self._run_rounds_legacy(state, staged)
+            else:
+                replicas, momentum, m = self._megabatch(
+                    state.replicas,
+                    state.momentum,
+                    staged.batches,
+                    staged.lr_dev,
+                    staged.mask,
+                    transforms=self._transforms,
+                )
 
         # ---- host work overlapped with the in-flight device program ----
         with trace_span("adapt", megabatch=mb):
+            # merge happens at the barrier and costs virtual time on every
+            # replica; the strategy decides how many merges a mega-batch
+            # incurs (per-round for eager synchronous schemes, once for
+            # barrier-only)
             n_merges = self.algo.merges_per_megabatch(plan)
             self.scheduler.clock.t[:] += self.merge_cost * n_merges
             virtual_time = float(self.scheduler.clock.t.max())
             new_b, new_lr = self.algo.adapt(state, plan, cfg)
-        if prefetch:
+        if prefetch and self.overlap:
             self._staged = self._stage_megabatch(new_b, new_lr, mb + 1)
 
         # ---- collect: the single host sync of the mega-batch ----
@@ -1428,6 +1220,10 @@ class ElasticTrainer:
             self._observe_window(plan, R, self.speed.elapsed(t_start))
 
         # ---- non-finite guard (DESIGN.md §7) ----
+        # A replica whose params went NaN/Inf during the rounds is healed
+        # *before* the barrier so it can never poison the merged global.
+        # Detection is read-only: a healthy mega-batch is bit-identical
+        # with the guard on or off.
         guard_repaired: list[int] = []
         if self.guard_nonfinite:
             finite = self._global_finite_rows(replicas)
@@ -1470,16 +1266,75 @@ class ElasticTrainer:
             info["guard_repaired"] = guard_repaired
         return new_state, info
 
+    def _run_rounds_legacy(self, state: ElasticState, staged: _StagedMegaBatch):
+        """The per-round host loop, the scan engine's test reference
+        (DESIGN.md §1): one jitted ``round_body`` dispatch and one metrics
+        read per staged round, bucket padding included. Returns what
+        ``self._megabatch`` returns under a host span — the per-round raw
+        sums, which ``_finish_metrics`` reduces with the in-program
+        arithmetic."""
+        replicas, momentum = state.replicas, state.momentum
+        sums = []
+        for i in range(staged.mask.shape[0]):
+            mask = staged.mask[i]
+            replicas, momentum, m = self._round(
+                replicas, momentum, {k: v[i] for k, v in staged.batches.items()},
+                staged.lr_dev, mask, transforms=self._transforms,
+            )
+            w = np.asarray(mask, np.float32)
+            sums.append([
+                np.sum(np.asarray(m[k], np.float32) * w)
+                for k in ("loss", "accuracy", "n_valid")
+            ] + [np.sum(w)])
+        return replicas, momentum, {"round_sums": np.asarray(sums, np.float32)}
+
+    def _bucket_rounds(self, plan) -> int:
+        """Rounds staged for ``plan``: its round count, padded to a power
+        of two with fully-masked rounds under ``round_bucket``."""
+        n = _next_pow2(plan.n_rounds) if self.round_bucket else plan.n_rounds
+        return max(n, 1)
+
+    def _slot_counters(self, plan, n_rounds: int) -> dict:
+        """What the device computes against what it holds, counted from the
+        plan: ``sample_slots`` (rounds × R × b_max) against ``samples``, and
+        for a provider with nnz slots (``max_nnz``) ``nnz_slots`` (samples ×
+        max_nnz) against ``nnz``, the dispatches' work units (Σ min(nnz_i,
+        max_nnz)). For a model with ``n_features``, ``w1_pad_rows``: the
+        zero rows w1 is stored with beyond them (a constant).
+        ``merge_flat_leaves``: the parameter leaves the merge kernel cannot
+        read as a device stores them, and flattens first (the replica axis
+        on the lanes; kernels/weighted_merge), for a device's replicas."""
+        samples = sum(d.n_samples for d in plan.dispatches)
+        out = {
+            "sample_slots": n_rounds * self.cfg.n_replicas * self.cfg.b_max,
+            "samples": samples,
+        }
+        max_nnz = getattr(self.provider, "max_nnz", None)
+        if max_nnz is not None:
+            out["nnz_slots"] = samples * max_nnz
+            out["nnz"] = sum(d.work for d in plan.dispatches)
+        if self._w1_pad_rows is not None:
+            out["w1_pad_rows"] = self._w1_pad_rows
+        r = self._mesh_width()
+        if self.cfg.placement == "sharded":
+            r //= self.mesh.shape[REPLICA_AXIS]
+        out["merge_flat_leaves"] = flat_leaves(jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct((r,) + l.shape, l.dtype),
+            self._param_shapes,
+        ))
+        return out
+
     def _finish_metrics(self, m) -> tuple[float, float]:
         """Collect a mega-batch's (loss, accuracy) from the device metrics.
 
-        Default engines return the fully-reduced scalars. The host-span
+        The scan engine returns the fully-reduced scalars. The host-span
         executor returns raw per-round sums over the *local* replicas
         (``round_sums``); the exchange completes the population sum and the
         host mirrors the in-jit normalization arithmetic in float32 — the
         only cross-process difference from the in-mesh psum path is float
         reassociation. A dead peer contributes nothing: that mega-batch's
-        metrics cover the survivors."""
+        metrics cover the survivors. The legacy engine returns the same
+        raw sums over every replica."""
         with trace_span("sync.metrics", megabatch=self._current_megabatch):
             if "round_sums" not in m:
                 return float(m["loss"]), float(m["accuracy"])
@@ -2117,7 +1972,6 @@ class ElasticTrainer:
         else:
             state = self.init_state()
         mlog = MetricsLog()
-        overlap_active = self.overlap and self.engine == "scan"
         pending_eval = None  # (mlog record to backfill, collector)
 
         def emit_line(record):
@@ -2155,7 +2009,7 @@ class ElasticTrainer:
             # host cursor consumed (no dangling prefetch in checkpoints or
             # for a caller that continues this trainer by hand)
             state, info = self.run_megabatch(
-                state, prefetch=overlap_active and (mb + 1 < n_megabatches)
+                state, prefetch=mb + 1 < n_megabatches
             )
             if checkpoint is not None:
                 with trace_span("checkpoint", megabatch=mb):
@@ -2165,7 +2019,7 @@ class ElasticTrainer:
             drain_eval()
             collect = None
             if test_batches is not None and (mb + 1) % eval_every == 0:
-                if overlap_active:
+                if self.overlap:
                     collect = self.evaluate_async(
                         state.global_model, test_batches
                     )

@@ -1,11 +1,8 @@
 """Serving launcher: batched prefill + decode for any assigned architecture.
 
-Serving path used by the decode dry-run shapes: prefill builds the KV/SSM
-cache for a batch of prompts, then ``decode_step`` generates tokens
-autoregressively (one token per step, cache updated in place functionally).
-
-On CPU this runs the reduced config; on TPU the full config under the
-production mesh with the serve sharding rules.
+Prefill builds the KV/SSM cache for a batch of prompts, then
+``decode_step`` generates tokens autoregressively (one token per step,
+cache updated in place functionally).
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --reduced \
       --batch 4 --context 64 --gen 16

@@ -1,10 +1,9 @@
-"""Input specs: ShapeDtypeStruct stand-ins for the dry-run and real random
-batches for smoke tests — one source of truth for every model input.
+"""Random model inputs for smoke tests — one source of truth for the LM
+batch layouts.
 
-Batch layouts per mode (leading replica dim R added by the caller/launcher):
+Batch layouts per mode (leading replica dim R added by the caller):
   train   : tokens/targets (B, S) int32, sample_mask (B,) bool
             [+ patch_embeds (B, P, Fd) for vlm; frames (B, F, Fd) for audio]
-  prefill : tokens (B, S) int32 [+ frontend embeds]
   decode  : tokens (B, 1) int32 + KV/SSM cache of seq_len context
 """
 from __future__ import annotations
@@ -13,75 +12,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import InputShape, ModelConfig
+from repro.configs.base import ModelConfig
 from repro.models import model as MDL
 
 
-def _frontend_fields(cfg: ModelConfig, b: int, as_spec: bool, rng=None) -> dict:
-    out = {}
-    if cfg.frontend == "vision":
-        shape = (b, cfg.frontend_len, cfg.frontend_dim)
-        out["patch_embeds"] = (
-            jax.ShapeDtypeStruct(shape, jnp.float32)
-            if as_spec
-            else jax.random.normal(rng, shape, jnp.float32)
-        )
-    elif cfg.frontend == "audio":
-        shape = (b, cfg.frontend_len, cfg.frontend_dim)
-        out["frames"] = (
-            jax.ShapeDtypeStruct(shape, jnp.float32)
-            if as_spec
-            else jax.random.normal(rng, shape, jnp.float32)
-        )
-    return out
-
-
-def train_specs(cfg: ModelConfig, b: int, s: int) -> dict:
-    return {
-        "tokens": jax.ShapeDtypeStruct((b, s), jnp.int32),
-        "targets": jax.ShapeDtypeStruct((b, s), jnp.int32),
-        "sample_mask": jax.ShapeDtypeStruct((b,), jnp.bool_),
-        **_frontend_fields(cfg, b, as_spec=True),
-    }
-
-
-def prefill_specs(cfg: ModelConfig, b: int, s: int) -> dict:
-    return {
-        "tokens": jax.ShapeDtypeStruct((b, s), jnp.int32),
-        **_frontend_fields(cfg, b, as_spec=True),
-    }
-
-
-def decode_specs(cfg: ModelConfig, b: int, s: int, window: int = 0) -> dict:
-    """Decode inputs: one new token + cache covering s context slots."""
-    cache = jax.eval_shape(lambda: MDL.init_cache(cfg, b, s, window))
-    return {
-        "tokens": jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        "cache": cache,
-    }
-
-
-def decode_window(cfg: ModelConfig, shape: InputShape) -> int:
-    """long_500k on full-attention archs uses the sliding-window carve-in."""
-    if shape.name != "long_500k":
-        return 0
-    if cfg.arch_type in ("ssm",):
-        return 0  # attention-free: native O(1) state
-    return cfg.long_context_window
-
-
-def input_specs(cfg: ModelConfig, shape: InputShape) -> dict:
-    b, s = shape.global_batch, shape.seq_len
-    if shape.mode == "train":
-        return train_specs(cfg, b, s)
-    if shape.mode == "prefill":
-        return prefill_specs(cfg, b, s)
-    return decode_specs(cfg, b, s, decode_window(cfg, shape))
-
-
-# --------------------------------------------------------------------------
-# real batches (smoke tests / examples)
-# --------------------------------------------------------------------------
+def _frontend_fields(cfg: ModelConfig, b: int, rng) -> dict:
+    """Random frontend embeddings of the vision and audio archs."""
+    name = {"vision": "patch_embeds", "audio": "frames"}.get(cfg.frontend)
+    if name is None:
+        return {}
+    shape = (b, cfg.frontend_len, cfg.frontend_dim)
+    return {name: jax.random.normal(rng, shape, jnp.float32)}
 
 
 def make_train_batch(cfg: ModelConfig, b: int, s: int, seed: int = 0) -> dict:
@@ -93,7 +34,7 @@ def make_train_batch(cfg: ModelConfig, b: int, s: int, seed: int = 0) -> dict:
         "sample_mask": jnp.ones((b,), jnp.bool_),
     }
     key = jax.random.PRNGKey(seed)
-    batch.update(_frontend_fields(cfg, b, as_spec=False, rng=key))
+    batch.update(_frontend_fields(cfg, b, key))
     return batch
 
 

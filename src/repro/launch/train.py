@@ -148,15 +148,15 @@ def main(argv=None):
                          " appear here automatically)")
     ap.add_argument("--engine", default="scan", choices=list(ENGINES),
                     help="mega-batch executor: device-resident scan (default)"
-                         " or the per-round host loop")
+                         " or a per-round host loop over the same staged"
+                         " mega-batch (the scan engine's test reference)")
     ap.add_argument("--overlap", default="on", choices=["on", "off"],
                     help="overlapped mega-batch pipeline (DESIGN.md §8):"
                          " stage mega-batch N+1 (plan + pack + upload) while"
-                         " N executes, and evaluate asynchronously. 'off' is"
-                         " the sequential differential oracle — bit-identical"
-                         " trajectories under the simulated speed model."
-                         " Only the scan engine pipelines; the legacy engine"
-                         " always runs sequentially")
+                         " N executes, and evaluate asynchronously. 'off'"
+                         " stages each mega-batch just in time and evaluates"
+                         " synchronously, on the same path — bit-identical"
+                         " trajectories under the simulated speed model")
     ap.add_argument("--placement", default="vmap", choices=list(PLACEMENTS),
                     help="replica placement: single-device vmap (default) or"
                          " shard_map over a 1-D replica device mesh (spans"

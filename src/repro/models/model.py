@@ -6,11 +6,11 @@ VLM/audio stub frontends. Layer stacks are grouped into (prefix, periodic
 blocks) so homogeneous spans run under ``lax.scan`` — compile time and HLO
 size stay bounded even for 72-layer hybrids.
 
-API (consumed by the trainer, launcher and dry-run):
+API (consumed by the trainer and the launchers):
     init(cfg, rng) -> params
-    loss_fn(cfg, params, batch) -> (loss, aux)           # train_4k
-    prefill(cfg, params, batch) -> (logits, cache)       # prefill_32k
-    decode_step(cfg, params, cache, tokens) -> (logits, cache)  # decode shapes
+    loss_fn(cfg, params, batch) -> (loss, aux)
+    prefill(cfg, params, batch) -> (logits, cache)
+    decode_step(cfg, params, cache, tokens) -> (logits, cache)
     init_cache(cfg, batch, max_len, window) -> cache
 """
 from __future__ import annotations
@@ -521,8 +521,8 @@ def decode_step(
 def prefill(cfg: ModelConfig, params: dict, batch: dict):
     """Full-sequence forward returning last-position logits.
 
-    (Prefill cache extraction is exercised via decode_step-based serving;
-    for the dry-run the prefill step is the forward itself.)
+    (Prefill cache extraction is exercised via decode_step-based
+    serving.)
     """
     memory = None
     if cfg.encoder_layers > 0:

@@ -94,7 +94,7 @@ def moe_ffn(
       * ``global``  — paper-era baseline: one argsort/gather/scatter over all
         T*k assignments. Under GSPMD with tokens sharded over the same axis
         as experts, the cross-shard scatter lowers to full-buffer
-        all-reduces (the dominant collective in the kimi/arctic dry-runs).
+        all-reduces.
       * ``sharded`` — beyond-paper optimization (EXPERIMENTS.md §Perf):
         dispatch is computed *per token shard* (vmapped over G groups
         aligned with the batch sharding), so gathers/scatters stay local

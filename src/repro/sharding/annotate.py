@@ -16,7 +16,8 @@ Logical axes used across the zoo:
   experts   — MoE expert dim
 
 Placement note (DESIGN.md §5): these annotations drive the *GSPMD* path
-(launch/steps.py under a production mesh), where the compiler partitions a
+(a step jitted under a (data, model) mesh, as
+tests/test_sharded_integration.py does), where the compiler partitions a
 single program. The trainer's ``placement='sharded'`` replica executor is
 the *manual* path — shard_map already fixes every leaf's layout via the
 replica-axis specs in sharding/rules.py, so no sharding context is

@@ -46,24 +46,26 @@ def test_baseline_predating_metric_does_not_crash():
 
 def test_new_benchmark_entry_is_informational():
     """A brand-new speedup key gates nothing but is shown as NEW."""
-    base = {"speedup_steps_per_s": {"engine_R1": 5.0}}
-    fresh = {"speedup_steps_per_s": {"engine_R1": 5.1, "engine_R8": 2.0}}
+    base = {"speedup_sparse_over_dense": {"fwd_bwd_nf10000": 2.0}}
+    fresh = {"speedup_sparse_over_dense": {"fwd_bwd_nf10000": 2.1,
+                                           "fwd_bwd_nf400000": 1.5}}
     failures, table = bench_check.check_file(
-        "BENCH_engine.json", fresh, base, tolerance=0.25
+        "BENCH_spmm_grad.json", fresh, base, tolerance=0.25
     )
     assert failures == []
-    assert any("engine_R8" in ln and ln.rstrip().endswith("NEW")
+    assert any("fwd_bwd_nf400000" in ln and ln.rstrip().endswith("NEW")
                for ln in table)
 
 
 def test_shared_metrics_still_gated_alongside_new_ones():
     """NEW-row tolerance must not weaken the gate for shared metrics."""
-    base = {"speedup_steps_per_s": {"engine_R1": 5.0}}
-    fresh = {"speedup_steps_per_s": {"engine_R1": 2.0, "engine_R8": 2.0}}
+    base = {"speedup_sparse_over_dense": {"fwd_bwd_nf10000": 2.0}}
+    fresh = {"speedup_sparse_over_dense": {"fwd_bwd_nf10000": 0.8,
+                                           "fwd_bwd_nf400000": 1.5}}
     failures, _ = bench_check.check_file(
-        "BENCH_engine.json", fresh, base, tolerance=0.25
+        "BENCH_spmm_grad.json", fresh, base, tolerance=0.25
     )
-    assert len(failures) == 1 and "engine_R1" in failures[0]
+    assert len(failures) == 1 and "fwd_bwd_nf10000" in failures[0]
 
 
 def test_metric_missing_from_fresh_still_fails():

@@ -1,7 +1,7 @@
 """End-to-end parity: model forward with Pallas kernels routed in
 (interpret mode on CPU) vs the pure-jnp paths. Covers the serving/forward
-path (kernels are forward-path drop-ins; training keeps the jnp paths,
-whose HLO the dry-run measures)."""
+path (kernels are forward-path drop-ins; training keeps the jnp
+paths)."""
 from __future__ import annotations
 
 import dataclasses

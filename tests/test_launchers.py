@@ -79,7 +79,7 @@ def test_benchmark_reexec_precedes_backend_init():
     JAX backend uninitialized, or a TPU would stay held by the old image."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     code = (
-        "import benchmarks.megabatch_engine, benchmarks.run\n"
+        "import benchmarks.run\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge.backends_are_initialized()\n"
     )

@@ -2,11 +2,10 @@
 
 Layers:
 
-* bit-identity — ``overlap=True`` (prefetch + async eval + fused staging)
-  must reproduce the sequential oracle ``overlap=False`` exactly: loss
-  trajectory, eval metrics, virtual clock, final params — for every
-  registered algorithm on both engines (the legacy engine never pipelines;
-  the dispatcher must still behave);
+* bit-identity — ``overlap=True`` (prefetch + async eval) must reproduce
+  ``overlap=False`` (the same path, staged just in time, eval synchronous)
+  exactly: loss trajectory, eval metrics, virtual clock, final params —
+  for every registered algorithm on both engines;
 * staging primitives — ``StagingBuffers`` double buffering and its in-use
   latch, lazy fetch + fused whole-plan gather vs the eager per-sample pack;
 * prefetch lifecycle — cursor snapshot/rollback on ``invalidate_prefetch``
@@ -105,7 +104,8 @@ def test_overlap_steady_state_never_retraces(case_ds):
     assert np.isfinite(info["train_loss"])
 
 
-def test_overlap_bit_identical_with_eval(case_ds):
+@pytest.mark.parametrize("engine", ["scan", "legacy_loop"])
+def test_overlap_bit_identical_with_eval(case_ds, engine):
     """Async eval (dispatched at the boundary, collected one boundary
     later) must publish the same metrics into the same records."""
     from repro.data.sparse import train_test_split
@@ -113,7 +113,7 @@ def test_overlap_bit_identical_with_eval(case_ds):
     train, test = train_test_split(case_ds, 0.25, seed=1)
 
     def go(overlap):
-        tr = build_case_trainer("adaptive", "scan", True, train)
+        tr = build_case_trainer("adaptive", engine, True, train)
         tr.overlap = overlap
         batches = tr.provider.test_batches(test, tr.cfg.b_max)
         _, mlog = tr.run(4, test_batches=batches, eval_every=2)
@@ -233,6 +233,19 @@ def test_prefetch_leaves_no_dangling_state_by_default(case_ds):
     assert tr._staged is None
 
 
+def test_overlap_off_stages_nothing_ahead(case_ds):
+    """With overlap off, ``prefetch=True`` is ignored: nothing is staged
+    and the host cursors stay where one sequential mega-batch leaves them."""
+    tr = _trainer("adaptive", "scan", case_ds, False)
+    ref = _trainer("adaptive", "scan", case_ds, False)
+    tr.run_megabatch(tr.init_state(), prefetch=True)
+    ref.run_megabatch(ref.init_state())
+    assert tr._staged is None
+    assert tr.provider.state_dict() == ref.provider.state_dict()
+    np.testing.assert_array_equal(tr.scheduler.clock.t, ref.scheduler.clock.t)
+    assert repr(tr.speed.state_dict()) == repr(ref.speed.state_dict())
+
+
 def test_invalidate_prefetch_rolls_cursors_back(case_ds):
     tr = _trainer("adaptive", "scan", case_ds, True)
     oracle = _trainer("adaptive", "scan", case_ds, False)
@@ -287,7 +300,8 @@ def test_checkpoint_mid_prefetch_uses_snapshot_cursors(case_ds):
 
 
 def test_overlap_off_consumes_stale_prefetch_safely(case_ds):
-    """Flipping overlap off between calls rolls the prefetch back."""
+    """Flipping overlap off between calls consumes the pending prefetch,
+    which holds the plan a sequential run makes."""
     tr = _trainer("adaptive", "scan", case_ds, True)
     oracle = _trainer("adaptive", "scan", case_ds, False)
     state = tr.init_state()

@@ -1,7 +1,11 @@
-"""Sharded-execution integration: run the elastic train round + Algorithm-2
-merge on a REAL (2, 2) mesh with 4 virtual CPU devices, and numerically
-compare against the single-device path. Run in a subprocess because the
-virtual device count must be fixed before jax initializes.
+"""Sharded-execution integration: run an elastic train round + Algorithm-2
+merge on a REAL (2, 2) (data, model) mesh with 4 virtual CPU devices under
+``sharding/rules.py::param_specs``, and numerically compare against the
+same round and merge on one device. The round (vmapped value-and-grad +
+masked ``sgd_update``) and the merge (``normalized_merge`` + broadcast) are
+written out here: the trainer places replicas on a 1-D replica mesh only.
+Run in a subprocess because the virtual device count must be fixed before
+jax initializes.
 """
 from __future__ import annotations
 
@@ -22,13 +26,15 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.configs.archs import ARCHS
+    from repro.core import adaptive_sgd as asgd
     from repro.launch import specs as SP
-    from repro.launch.steps import make_merge_step, make_train_round
+    from repro.optim.sgd import SGDConfig, sgd_update
     from repro.sharding.annotate import sharding_context
     from repro.sharding.rules import (
         MeshAxes, param_specs, to_named, train_batch_specs,
     )
     from repro.models import model as MDL
+    from repro.utils import tree as tu
 
     cfg = ARCHS["llama3.2-1b"].reduced()
     mesh = jax.make_mesh((2, 2), ("data", "model"),
@@ -45,8 +51,20 @@ SCRIPT = textwrap.dedent("""
     lr = jnp.full((R,), 0.1, jnp.float32)
     mask = jnp.ones((R,), jnp.float32)
 
-    step = make_train_round(cfg)
-    merge = make_merge_step(cfg, keep_global=False)
+    def step(replicas, batch, lr_vec, update_mask):
+        grad_fn = jax.value_and_grad(
+            lambda p, b: MDL.loss_fn(cfg, p, b), has_aux=True
+        )
+        (loss, aux), grads = jax.vmap(grad_fn)(replicas, batch)
+        new_replicas, _ = sgd_update(
+            replicas, grads, lr_vec, SGDConfig(),
+            update_mask=update_mask, replica_dim=True,
+        )
+        return new_replicas, {"loss": loss, "accuracy": aux["accuracy"]}
+
+    def merge(replicas, alphas):
+        new_global = asgd.normalized_merge(replicas, alphas, None, None, 0.0)
+        return tu.tree_broadcast_replicas(new_global, R)
 
     # ---- single device reference ----
     ref_replicas, ref_m = jax.jit(step)(replicas, rbatch, lr, mask)

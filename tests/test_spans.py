@@ -8,7 +8,8 @@ counters of each mega-batch's record.
 * scopes — the lowered mega-batch, merge and eval programs name the input
   layer, the head, the sparse and dense updates and the merge;
 * counters — ``sample_slots`` / ``samples`` / ``nnz_slots`` / ``nnz`` in
-  ``info`` agree with the plan, on both mega-batch paths; ``w1_pad_rows``
+  ``info`` agree with the plan, on both engines, with overlap on and off;
+  ``w1_pad_rows``
   with w1's stored shape; ``merge_flat_leaves`` is 0 for the XML model;
 * cost — the arithmetic does not change: a traced run is bit-identical to
   an untraced one.
@@ -101,6 +102,29 @@ def test_host_spans_in_profiler_trace(split, tmp_path):
     assert staged == [0, 1]
 
 
+def test_stage_spans_match_with_overlap_off(split, tmp_path):
+    """One staging path: overlap on and off open the same ``stage*``
+    spans for every mega-batch, prefetched or staged just in time."""
+    train, _ = split
+
+    def stage_spans(overlap):
+        tr = build_case_trainer("adaptive", "scan", True, train)
+        tr.overlap = overlap
+        trace = str(tmp_path / f"trace_{overlap}")
+        with jax.profiler.trace(trace):
+            tr.run(3)
+        out = {}
+        for name, _, _, args in _host_spans(trace):
+            if name.startswith("stage"):
+                out.setdefault(args["megabatch"], []).append(name)
+        return {mb: sorted(names) for mb, names in out.items()}
+
+    on = stage_spans(True)
+    assert sorted(on) == [0, 1, 2]
+    assert on[0] == ["stage", "stage.pack", "stage.plan", "stage.upload"]
+    assert on == stage_spans(False)
+
+
 def _scopes(lowered) -> set[str]:
     """The name-stack components of a lowered program's op locations, with
     transform wrappers (``vmap(...)``, ``transpose(jvp(...))``) peeled."""
@@ -127,10 +151,11 @@ def test_named_scopes_in_lowered_programs(split):
         tr._eval.lower(state.global_model, batch))
 
 
+@pytest.mark.parametrize("engine", ["scan", "legacy_loop"])
 @pytest.mark.parametrize("overlap", [True, False])
-def test_slot_counters_follow_the_plan(split, overlap):
+def test_slot_counters_follow_the_plan(split, overlap, engine):
     train, _ = split
-    tr = build_case_trainer("adaptive", "scan", True, train)
+    tr = build_case_trainer("adaptive", engine, True, train)
     tr.overlap = overlap
     cfg = tr.cfg
     k = tr.provider.max_nnz
